@@ -24,7 +24,12 @@ import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sps
 
-from amgx_tpu.core.profiling import setup_fastpath_enabled, setup_phase
+from amgx_tpu.core.profiling import (
+    setup_download,
+    setup_fastpath_enabled,
+    setup_phase,
+    setup_upload,
+)
 
 
 def edge_weights(Asp: sps.csr_matrix, formula: int = 0) -> sps.csr_matrix:
@@ -772,14 +777,54 @@ def geo_galerkin_dia(Asp, grid, block):
     bx, by, bz = block
     if nx % bx or ny % by or nz % bz:
         return None  # ragged blocks: fall back
-    cx, cy, cz = nx // bx, ny // by, nz // bz
+    with setup_phase("rap_diagonals"):
+        found = _geo_rap_diagonals(Asp, grid, max(bx, by, bz))
+    if found is None:
+        return None
+    dia, decs = found
+    # wrap detection + windowed block reductions run as ONE jitted
+    # device program (_geo_rap_device): on TPU the Galerkin reductions
+    # — the largest remaining setup stage — leave the host.  When the
+    # device would silently downcast f64 (x64 disabled, the usual TPU
+    # setting), keep the exact host reductions instead: the coarse
+    # operator's precision and the wrap check must not degrade.
+    use_device = not (
+        np.dtype(Asp.dtype) == np.float64
+        and not jax.config.jax_enable_x64
+    )
+    if use_device:
+        dia = setup_upload(dia, "rap_upload")
+        with setup_phase("rap_device", "device"):
+            wrap_bad, stacked = _geo_rap_device(
+                dia, grid, (bx, by, bz), decs
+            )
+            wrap_bad = bool(wrap_bad)
+        if not wrap_bad:
+            stacked = setup_download(stacked, "rap_download")
+    else:
+        with setup_phase("rap_host"):
+            wrap_bad, stacked = _geo_rap_host(
+                dia, grid, (bx, by, bz), decs
+            )
+    if wrap_bad:
+        # periodic/wrap diagonals (e.g. +-(nx-1)) carry nonzeros at
+        # out-of-window rows — geometric attribution would be wrong
+        return None
+    with setup_phase("rap_assemble"):
+        return _geo_rap_assemble(stacked, grid, (bx, by, bz), decs)
+
+
+def _geo_rap_diagonals(Asp, grid, reach):
+    """Dense diagonals ``[n_offsets, n]`` of a stencil matrix and each
+    offset's (dx, dy, dz) displacement, or None when an offset does not
+    decompose on ``grid``."""
+    nx, ny, nz = grid
     n = nx * ny * nz
     rows_all = np.repeat(
         np.arange(n, dtype=np.int64), np.diff(Asp.indptr)
     )
     d_all = Asp.indices.astype(np.int64) - rows_all
     offs_arr = np.unique(d_all)
-    reach = max(bx, by, bz)
     dec = {}
     for off in offs_arr:
         d = _decompose_offset(int(off), nx, ny, nz, reach)
@@ -792,32 +837,15 @@ def geo_galerkin_dia(Asp, grid, block):
     k_all = np.searchsorted(offs_arr, d_all)
     dia = np.zeros((offs_arr.shape[0], n), dtype=Asp.dtype)
     dia[k_all, rows_all] = Asp.data
+    return dia, tuple(dec[int(off)] for off in offs_arr)
 
-    # wrap detection + windowed block reductions run as ONE jitted
-    # device program (_geo_rap_device): on TPU the Galerkin reductions
-    # — the largest remaining setup stage — leave the host.  When the
-    # device would silently downcast f64 (x64 disabled, the usual TPU
-    # setting), keep the exact host reductions instead: the coarse
-    # operator's precision and the wrap check must not degrade.
-    decs = tuple(dec[int(off)] for off in offs_arr)
-    use_device = not (
-        np.dtype(Asp.dtype) == np.float64
-        and not jax.config.jax_enable_x64
-    )
-    if use_device:
-        wrap_bad, stacked = _geo_rap_device(
-            jnp.asarray(dia), grid, (bx, by, bz), decs
-        )
-        wrap_bad = bool(wrap_bad)
-        stacked = None if wrap_bad else np.asarray(stacked)
-    else:
-        wrap_bad, stacked = _geo_rap_host(
-            dia, grid, (bx, by, bz), decs
-        )
-    if wrap_bad:
-        # periodic/wrap diagonals (e.g. +-(nx-1)) carry nonzeros at
-        # out-of-window rows — geometric attribution would be wrong
-        return None
+
+def _geo_rap_assemble(stacked, grid, block, decs):
+    """The coarse CSR from the stacked coarse diagonals of
+    :func:`_geo_rap_device` / :func:`_geo_rap_host`."""
+    nx, ny, nz = grid
+    bx, by, bz = block
+    cx, cy, cz = nx // bx, ny // by, nz // bz
     keys = _geo_rap_keys((bx, by, bz), decs)
     coarse = {k: stacked[i] for i, k in enumerate(keys)}
 
@@ -871,9 +899,10 @@ def build_aggregation_level(Asp, cfg, scope):
         # the serve path hands the READ-ONLY host_csr view of a padded
         # pattern, which can carry duplicate filler entries; scipy's
         # abs()/binops dedup IN PLACE, so canonicalize a private copy
-        Asp = Asp.copy()
-        Asp.sum_duplicates()
-        Asp.sort_indices()
+        with setup_phase("canonicalize"):
+            Asp = Asp.copy()
+            Asp.sum_duplicates()
+            Asp.sort_indices()
     with setup_phase("aggregation"):
         agg, geo_info = select_aggregates(Asp, cfg, scope)
     n = Asp.shape[0]

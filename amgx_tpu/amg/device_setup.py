@@ -36,7 +36,6 @@ back to the host path (``amg/classical.py``) level-by-level.
 from __future__ import annotations
 
 import functools
-import time
 
 import numpy as np
 import scipy.sparse as sps
@@ -50,25 +49,12 @@ from amgx_tpu.core import profiling
 from amgx_tpu.core.errors import ResourceError
 
 
-def _hash_weights(n, seed=0, acc=None):
-    """Tie-break hash weights, with the host seconds they cost added
-    to the caller's PER-CALL accumulator ``acc`` (a one-element list):
-    the O(n) numpy hashes run between device kernels and must count as
-    HOST work in the placement profile.  The accumulator used to be a
-    module-global list, which concurrent setups (serve compile worker
-    + foreground) corrupted — each build now owns its accumulator."""
-    t0 = time.perf_counter()
-    out = _hash_weights_raw(n, seed=seed)
-    if acc is not None:
-        acc[0] += time.perf_counter() - t0
-    return out
-
-
-# profile of the most recent level build (host vs device split) —
-# INFORMATIONAL only (last writer wins under concurrency); callers
-# that need reliable attribution pass ``profile=`` to
-# build_classical_level_device and read their own dict
-last_profile: dict = {}
+def _hash_weights(n, seed=0):
+    """Tie-break hash weights: O(n) numpy hashes that run between
+    device kernels, as a host span inside the device span around
+    them."""
+    with profiling.setup_phase("hash_weights"):
+        return _hash_weights_raw(n, seed=seed)
 
 
 def _bucket(x: int, floor: int = 128) -> int:
@@ -557,15 +543,14 @@ def _compact_masked(rows, cols, vals, keep, sentinel_row):
 # aggressive two-stage PMIS (reference selectors AGGRESSIVE_PMIS)
 
 
-def aggressive_pmis_device(rows, cols, vals, strong, n, dtype,
-                           hash_acc=None):
+def aggressive_pmis_device(rows, cols, vals, strong, n, dtype):
     """Two-stage aggressive coarsening: PMIS on S, then PMIS (seed 1)
     among the stage-1 C points on the distance-2 graph S + S@S —
     bit-compatible with the host ``aggressive_pmis_select``."""
     fdt = jnp.float64 if dtype == np.float64 else jnp.float32
     lam = jax.ops.segment_sum(
         strong.astype(fdt), jnp.minimum(cols, n - 1), num_segments=n)
-    w0 = lam + jnp.asarray(_hash_weights(n, seed=0, acc=hash_acc), fdt)
+    w0 = lam + jnp.asarray(_hash_weights(n, seed=0), fdt)
     cf1 = _pmis_dev(rows, cols, strong, n, w0).astype(jnp.int32)
     nc1 = int(cf1.sum())  # scalar sync
     if nc1 <= 1:
@@ -590,7 +575,7 @@ def aggressive_pmis_device(rows, cols, vals, strong, n, dtype,
     edgeC = crow < nc1
     lam2 = jax.ops.segment_sum(
         edgeC.astype(fdt), jnp.minimum(ccol, nc1 - 1), num_segments=nc1)
-    w2 = lam2 + jnp.asarray(_hash_weights(nc1, seed=1, acc=hash_acc), fdt)
+    w2 = lam2 + jnp.asarray(_hash_weights(nc1, seed=1), fdt)
     cf2 = _pmis_dev(crow, ccol, edgeC, nc1, w2)
     # scatter back: final C = stage-1 C that survived stage 2
     cf = (cf1 == 1) & (cf2.astype(jnp.int32)[
@@ -768,21 +753,18 @@ def standard_interpolation_device(rows, cols, vals, strong, cf, n,
 # Galerkin chain
 
 
-def galerkin_rap_device(rows, cols, vals, prow, pcol, pval,
-                        n, nc, prof=None):
+def galerkin_rap_device(rows, cols, vals, prow, pcol, pval, n, nc):
     """The per-level Galerkin tail — R = P^T, AP = A @ P, Ac = R @ AP
     — as ONE driver call over the ESC kernels, with the expand/sort
     intermediates donated into their compress stages (_compress_jit).
     The only host round-trips are the four scalar size syncs of the
-    two products (the reference csr_multiply.cu counter readbacks);
-    they are counted into ``prof`` and the module-level setup-sync
-    hook.  Returns ((rrow, rcol, rval), (ac_rows, ac_cols, ac_vals,
-    nnz_ac))."""
+    two products (the reference csr_multiply.cu counter readbacks),
+    counted through the setup-sync hook.  Returns ((rrow, rcol, rval),
+    (ac_rows, ac_cols, ac_vals, nnz_ac))."""
     rrow, rcol, rval = _transpose_dev(prow, pcol, pval, n, nc)
     ap = spgemm_device(rows, cols, vals, n, prow, pcol, pval, n)
     ac = spgemm_device(rrow, rcol, rval, nc, ap[0], ap[1], ap[2], n)
-    if prof is not None:
-        prof["syncs"] = prof.get("syncs", 0) + 4
+    profiling.count_setup_sync(4)
     return (rrow, rcol, rval), ac
 
 
@@ -821,29 +803,25 @@ def device_setup_eligible(cfg, scope, level_id: int,
 def _coo_to_scipy(rows, cols, vals, nnz, shape):
     """Row-major-sorted unique COO -> scipy CSR without a host sort
     (indptr by bincount; O(nnz) array assembly only)."""
-    r = np.asarray(rows[:nnz])
-    c = np.asarray(cols[:nnz])
-    v = np.asarray(vals[:nnz])
+    r, c, v = profiling.setup_download(
+        (rows[:nnz], cols[:nnz], vals[:nnz]), "device_download")
     indptr = np.zeros(shape[0] + 1, np.int64)
     np.cumsum(np.bincount(r, minlength=shape[0]), out=indptr[1:])
     return sps.csr_matrix((v, c.astype(np.int64), indptr), shape=shape)
 
 
-def build_classical_level_device(Asp, cfg, scope, level_id: int = 0,
-                                 profile: dict | None = None):
+def build_classical_level_device(Asp, cfg, scope, level_id: int = 0):
     """One classical level on device (strength -> PMIS -> D1 -> RAP).
 
-    Returns (P, R, Ac) as scipy CSR for the driver loop.  The
-    host/device timing split accumulates into ``profile`` when given
-    (per-call state — safe under concurrent setups); ``last_profile``
-    still mirrors the most recent build for interactive inspection.
-    Raises nothing: callers gate on :func:`device_setup_eligible`.
+    Returns (P, R, Ac) as scipy CSR for the driver loop.  The build is
+    five set-up spans: ``device_pad`` (host), ``device_upload``
+    (transfer), ``device_build`` (device, from the first dispatch to
+    the Galerkin product's readiness; its host hashes are a nested
+    ``hash_weights`` host span), ``device_download`` (transfer) and
+    ``device_assemble`` (host); the scalar syncs are counted through
+    ``profiling.count_setup_sync``.  Raises nothing: callers gate on
+    :func:`device_setup_eligible`.
     """
-    import warnings
-
-    global last_profile
-    prof = {"host_s": 0.0, "device_s": 0.0, "syncs": 0}
-    hash_acc = [0.0]  # per-call (was a corruptible module global)
     theta = float(cfg.get("strength_threshold", scope))
     max_row_sum = float(cfg.get("max_row_sum", scope))
     selector = str(cfg.get("selector", scope)).upper()
@@ -858,21 +836,38 @@ def build_classical_level_device(Asp, cfg, scope, level_id: int = 0,
         or selector in ("AGGRESSIVE_PMIS", "AGGRESSIVE_HMIS")
     )
 
-    t0 = time.perf_counter()
-    A = Asp.tocsr()
-    n = A.shape[0]
-    nnz = A.indices.shape[0]
-    rows_np = np.repeat(np.arange(n, dtype=np.int32), np.diff(A.indptr))
-    size = _bucket(nnz)
-    r_np, c_np, v_np = _pad_coo(
-        rows_np, A.indices.astype(np.int32), A.data, size, n
-    )
-    prof["host_s"] += time.perf_counter() - t0
+    with profiling.setup_phase("device_pad"):
+        A = Asp.tocsr()
+        n = A.shape[0]
+        nnz = A.indices.shape[0]
+        rows_np = np.repeat(np.arange(n, dtype=np.int32),
+                            np.diff(A.indptr))
+        size = _bucket(nnz)
+        r_np, c_np, v_np = _pad_coo(
+            rows_np, A.indices.astype(np.int32), A.data, size, n
+        )
+    rows, cols, vals = profiling.setup_upload(
+        (r_np, c_np, v_np), "device_upload")
+    with profiling.setup_phase("device_build", "device"):
+        (prow, pcol, pval, nnzP, nc), (rrow, rcol, rval), ac = \
+            _build_level_dev(rows, cols, vals, n, Asp.dtype, theta,
+                             max_row_sum, selector, interp, trunc,
+                             max_el, aggressive, aggressive_interp)
+    with profiling.setup_phase("device_assemble"):
+        P = _coo_to_scipy(prow, pcol, pval, nnzP, (n, nc))
+        R = _coo_to_scipy(rrow, rcol, rval, nnzP, (nc, n))
+        Ac = _coo_to_scipy(ac[0], ac[1], ac[2], ac[3], (nc, nc))
+    return P, R, Ac
 
-    t0 = time.perf_counter()
-    rows = jnp.asarray(r_np)
-    cols = jnp.asarray(c_np)
-    vals = jnp.asarray(v_np)
+
+def _build_level_dev(rows, cols, vals, n, dtype, theta, max_row_sum,
+                     selector, interp, trunc, max_el, aggressive,
+                     aggressive_interp):
+    """The device programs of one classical level, from strength to
+    the Galerkin product, returned once the product is ready:
+    ``(P coo, nnz P, coarse rows), R coo, Ac coo and nnz``."""
+    import warnings
+
     fdt = jnp.float64 if vals.dtype == jnp.float64 else jnp.float32
     strong = _strength_ahat_dev(rows, cols, vals, n, theta, max_row_sum)
 
@@ -883,11 +878,11 @@ def build_classical_level_device(Asp, cfg, scope, level_id: int = 0,
                 "using MULTIPASS"
             )
         cf, nc = aggressive_pmis_device(rows, cols, vals, strong, n,
-                                        Asp.dtype, hash_acc=hash_acc)
-        prof["syncs"] += 4
+                                        dtype)
+        profiling.count_setup_sync(4)
         prow, pcol, pval, nnzP, nc = multipass_interpolation_device(
             rows, cols, vals, strong, cf, n)
-        prof["syncs"] += 4
+        profiling.count_setup_sync(4)
     else:
         # PMIS weights: S^T degree + hash (f64, identical to host;
         # seed=0 matches the host pmis_select stage-0 seed)
@@ -896,19 +891,19 @@ def build_classical_level_device(Asp, cfg, scope, level_id: int = 0,
             num_segments=n,
         )
         wdev = lam + jnp.asarray(
-            _hash_weights(n, seed=0, acc=hash_acc), fdt
+            _hash_weights(n, seed=0), fdt
         )
         cf = _pmis_dev(rows, cols, strong, n, wdev)
         if interp == "MULTIPASS":
             prow, pcol, pval, nnzP, nc = multipass_interpolation_device(
                 rows, cols, vals, strong, cf, n)
-            prof["syncs"] += 4
+            profiling.count_setup_sync(4)
         elif interp == "D1":
             pvals, keep, cmap = _d1_weights_dev(
                 rows, cols, vals, strong, cf.astype(jnp.int32), n)
             nf = int(keep.sum())     # scalar sync
             nc = int(cf.sum())       # scalar sync
-            prof["syncs"] += 2
+            profiling.count_setup_sync(2)
             nnzP = nf + nc
             p_size = _bucket(nnzP)
             prow, pcol, pval = _sort_coo(*_assemble_p_dev(
@@ -917,33 +912,15 @@ def build_classical_level_device(Asp, cfg, scope, level_id: int = 0,
             ))
         else:  # D2 / STD / STANDARD
             prow, pcol, pval, nnzP, nc = standard_interpolation_device(
-                rows, cols, vals, strong, cf, n, Asp.dtype)
-            prof["syncs"] += 6
+                rows, cols, vals, strong, cf, n, dtype)
+            profiling.count_setup_sync(6)
 
     prow, pcol, pval, nnzP = truncate_interp_device(
         prow, pcol, pval, nnzP, n, trunc, max_el)
     # Galerkin tail (transpose + AP + RAP) as one driver call with
     # donated expand/sort intermediates
     (rrow, rcol, rval), ac = galerkin_rap_device(
-        rows, cols, vals, prow, pcol, pval, n, nc, prof=prof
+        rows, cols, vals, prow, pcol, pval, n, nc
     )
     jax.block_until_ready(ac[2])
-    # hash generation ran on host between kernels: reattribute
-    prof["device_s"] += time.perf_counter() - t0 - hash_acc[0]
-    prof["host_s"] += hash_acc[0]
-
-    t0 = time.perf_counter()
-    P = _coo_to_scipy(prow, pcol, pval, nnzP, (n, nc))
-    R = _coo_to_scipy(rrow, rcol, rval, nnzP, (nc, n))
-    Ac = _coo_to_scipy(ac[0], ac[1], ac[2], ac[3], (nc, nc))
-    prof["host_s"] += time.perf_counter() - t0
-    if profile is not None:
-        for k, v in prof.items():
-            profile[k] = profile.get(k, 0) + v
-    # ONE module-hook update covering the whole build, so the
-    # test-countable setup_sync_count agrees exactly with the
-    # per-call profile's sync ledger (aggressive/multipass/D2 paths
-    # included), instead of only the Galerkin tail's share
-    profiling.count_setup_sync(prof["syncs"])
-    last_profile = prof
-    return P, R, Ac
+    return (prow, pcol, pval, nnzP, nc), (rrow, rcol, rval), ac

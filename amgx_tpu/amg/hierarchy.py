@@ -26,6 +26,7 @@ from amgx_tpu.core.matrix import SparseMatrix
 from amgx_tpu.core.profiling import (
     named_scope,
     setup_fastpath_enabled,
+    setup_level,
     setup_phase,
     setup_profile_scope,
     setup_transfer,
@@ -203,9 +204,10 @@ class AMGSolver(Solver):
             self.coarsest_sweeps = max(self.coarsest_sweeps, 8)
         self.levels: list[AMGLevel] = []
         self.coarse_solver: Solver | None = None
-        # host/device split of setup time when the device-resident
-        # classical pipeline runs (amg/device_setup.py); empty for the
-        # host path.  Keys: host_s, device_s, syncs.
+        # setup-phase profile (core/profiling.py): inclusive seconds
+        # per phase, the self-time totals host_s / device_s /
+        # transfer_s / compile_s / unattributed_s, and counters
+        # (syncs, transfer_batches, transfer_arrays)
         self.setup_profile: dict = {}
         # classical setup: which builder ("device" / "host") built each
         # coarse level, in level order — the device pipeline's only
@@ -261,12 +263,8 @@ class AMGSolver(Solver):
                 from amgx_tpu.amg.device_setup import DeviceSetupOverflow
 
                 try:
-                    # per-call profile out-param (the old module-global
-                    # last_profile read was corruptible by concurrent
-                    # setups on the serve compile worker)
                     out = build_classical_level_device(
-                        Asp, self.cfg, self.scope, level_id,
-                        profile=self.setup_profile,
+                        Asp, self.cfg, self.scope, level_id
                     )
                 except DeviceSetupOverflow as e:
                     # the one known device limit: an ESC expansion past
@@ -389,7 +387,7 @@ class AMGSolver(Solver):
             # construction-time host memo instead of a device->host
             # download (the first of the ping-pongs cold setup used to
             # pay); the reference path keeps the download
-            with setup_phase("host_csr"):
+            with setup_phase("host_csr", "transfer"):
                 Asp = (
                     A.host_csr() if setup_fastpath_enabled()
                     else A.to_scipy()
@@ -453,46 +451,46 @@ class AMGSolver(Solver):
                 or n <= self.min_fine_rows
             ):
                 break
-            P, R, Ac = self._build_coarse(Asp, lvl.level_id)
-            nc = Ac.shape[0]
-            # stall: empty, non-shrinking, or shrinking slower than
-            # coarsen_threshold allows (reference amg.cu:365-370)
-            if nc >= n or nc == 0 or nc > self.coarsen_threshold * n:
-                break
-            dtype = lvl.A.values.dtype
-            if self.coarse_reorder != "NONE":
-                # coarse numbering is internal: renumber gather-bound
-                # Galerkin operators for column locality (windowed kernel)
-                from amgx_tpu.ops.reorder import reorder_coarse_level
+            with setup_level(lvl.level_id):
+                P, R, Ac = self._build_coarse(Asp, lvl.level_id)
+                nc = Ac.shape[0]
+                # stall: empty, non-shrinking, or shrinking slower than
+                # coarsen_threshold allows (reference amg.cu:365-370)
+                if nc >= n or nc == 0 or nc > self.coarsen_threshold * n:
+                    break
+                dtype = lvl.A.values.dtype
+                if self.coarse_reorder != "NONE":
+                    # coarse numbering is internal: renumber gather-bound
+                    # Galerkin operators for column locality (windowed kernel)
+                    from amgx_tpu.ops.reorder import reorder_coarse_level
 
-                P, R, Ac = reorder_coarse_level(P, R, Ac, dtype)
-            lvl.P = SparseMatrix.from_scipy(
-                P.astype(dtype, copy=False), device=not defer
-            )
-            lvl.R = SparseMatrix.from_scipy(
-                R.astype(dtype, copy=False), device=not defer
-            )
-            Ac = Ac.astype(dtype, copy=False)
-            if self.structure_reuse != 0:
-                with setup_phase("rap_plan"):
-                    lvl.rap_plan = self._try_plan_rap(
-                        R, Asp, P, Ac, device=not defer
+                    with setup_phase("reorder"):
+                        P, R, Ac = reorder_coarse_level(P, R, Ac, dtype)
+                with setup_phase("level_formats"):
+                    lvl.P = SparseMatrix.from_scipy(
+                        P.astype(dtype, copy=False), device=not defer
                     )
-            self.levels.append(
-                AMGLevel(
-                    # Galerkin products of constant stencils on
-                    # divisible grids stay constant stencils, so the
-                    # matrix-free format propagates down the hierarchy
-                    # (each level re-verified independently)
-                    SparseMatrix.from_scipy(
+                    lvl.R = SparseMatrix.from_scipy(
+                        R.astype(dtype, copy=False), device=not defer
+                    )
+                    Ac = Ac.astype(dtype, copy=False)
+                if self.structure_reuse != 0:
+                    with setup_phase("rap_plan"):
+                        lvl.rap_plan = self._try_plan_rap(
+                            R, Asp, P, Ac, device=not defer
+                        )
+                with setup_phase("level_formats"):
+                    # Galerkin products of constant stencils on divisible
+                    # grids stay constant stencils, so the matrix-free format
+                    # propagates down the hierarchy (each level re-verified
+                    # independently)
+                    Ac_dev = SparseMatrix.from_scipy(
                         Ac, device=not defer,
                         accel_formats=self._accel_formats(),
-                    ),
-                    len(self.levels),
-                )
-            )
-            self.setup_stats["levels_built"] += 1
-            Asp = Ac
+                    )
+                self.levels.append(AMGLevel(Ac_dev, len(self.levels)))
+                self.setup_stats["levels_built"] += 1
+                Asp = Ac
 
     @staticmethod
     def _try_plan_rap(R, Asp, P, Ac, device: bool = True):
@@ -689,7 +687,8 @@ class AMGSolver(Solver):
         # host-resident at cold setup, so the reduced bytes are what
         # ships; smoothers and the coarse solver then derive their
         # state from the cast operators
-        self._cast_hierarchy()
+        with setup_phase("cast"):
+            self._cast_hierarchy()
         self._upload_levels()
         # smoothers on all but the coarsest; coarse solver on the last.
         # reuse_smoothers (store-restore path ONLY): keep smoothers the
@@ -698,14 +697,16 @@ class AMGSolver(Solver):
         with setup_phase("finalize"):
             for lvl in self.levels[:-1]:
                 if not (reuse_smoothers and lvl.smoother is not None):
-                    self._refresh_smoother(lvl)
+                    with setup_level(lvl.level_id):
+                        self._refresh_smoother(lvl)
         coarsest = self.levels[-1]
         # the coarse-solver build gets its own profiler phase: a
         # DenseLU bottom's O(n^3) factorization used to hide inside
         # "finalize", which made the coarse_solver=INEXACT win
         # invisible in setup_profile and the
         # amgx_setup_phase_seconds_total family
-        with setup_phase("coarse_factor"):
+        with setup_phase("coarse_factor", "device"), \
+                setup_level(coarsest.level_id):
             restored = getattr(self, "_restored_coarse", None)
             self._restored_coarse = None
             if reuse_smoothers and restored is not None:

@@ -252,7 +252,9 @@ def validate_csr(row_offsets, col_indices, values, n_rows, n_cols,
 def validate_operator(A, where="solver setup"):
     """Numeric sanity of an already-constructed SparseMatrix (setup
     boundary: coefficients may have been replaced since upload)."""
-    vals = np.asarray(A.values)
+    from amgx_tpu.core.profiling import setup_download
+
+    vals = setup_download(A.values)
     if vals.size and np.issubdtype(vals.dtype, np.inexact) \
             and not np.all(np.isfinite(vals)):
         raise NonFiniteValuesError(

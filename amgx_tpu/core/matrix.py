@@ -361,24 +361,68 @@ class SparseMatrix:
         removes).  Host-resident matrices are construction-time
         intermediates: solve paths expect device leaves.
         """
-        row_offsets = np.asarray(row_offsets, dtype=np.int32)
-        col_indices = np.asarray(col_indices, dtype=np.int32)
-        values = np.asarray(values)
-        if dtype is not None:
-            values = values.astype(dtype)
+        from amgx_tpu.core import errors as _errors
+        from amgx_tpu.core.profiling import setup_phase
+
+        with setup_phase("formats"):
+            row_offsets = np.asarray(row_offsets, dtype=np.int32)
+            col_indices = np.asarray(col_indices, dtype=np.int32)
+            values = np.asarray(values)
+            if dtype is not None:
+                values = values.astype(dtype)
         n_rows = row_offsets.shape[0] - 1
         if n_cols is None:
             n_cols = n_rows
-        from amgx_tpu.core import errors as _errors
-
         if validate is None:
             validate = _errors.validation_enabled()
         if validate:
-            _errors.validate_csr(
+            with setup_phase("validate"):
+                _errors.validate_csr(
+                    row_offsets, col_indices, values, n_rows, n_cols,
+                    block_size=block_size,
+                )
+        with setup_phase("formats"):
+            host = SparseMatrix._host_formats(
                 row_offsets, col_indices, values, n_rows, n_cols,
-                block_size=block_size,
+                block_size, build_ell, partition, accel_formats,
             )
-        b = block_size
+        if device:
+            with setup_phase("upload", "transfer"):
+                arrays = {
+                    k: None if v is None else jnp.asarray(v)
+                    for k, v in host["arrays"].items()
+                }
+                jax.block_until_ready(arrays)
+        else:
+            arrays = host["arrays"]  # host-resident build
+        m = SparseMatrix(
+            **arrays,
+            ell_wwidth=host["ell_wwidth"],
+            n_rows=int(n_rows),
+            n_cols=int(n_cols),
+            block_size=int(block_size),
+            dia_offsets=host["dia_offsets"],
+            mf_meta=host["mf_meta"],
+            views=views,
+            partition=partition,
+        )
+        if device:
+            from amgx_tpu.core import profiling
+
+            # eager per-matrix upload: counts as one transfer batch
+            # when a setup profile is active (the reference cold-setup
+            # path performs several of these per level; the fast path's
+            # single batched finalize is asserted against this hook)
+            if profiling.active_setup_profile() is not None:
+                n_arr = sum(v is not None for v in arrays.values())
+                profiling.count_setup_transfer(n_arr)
+        return m
+
+    @staticmethod
+    def _host_formats(row_offsets, col_indices, values, n_rows, n_cols,
+                      b, build_ell, partition, accel_formats) -> dict:
+        """The host arrays of a ``from_csr`` build: CSR, diagonal and
+        the acceleration formats each gate admits."""
         if b == 1:
             values = values.reshape(-1)
         else:
@@ -490,56 +534,30 @@ class SparseMatrix:
                     if built is not None:
                         ell_wcols, ell_wvals, ell_wbase, ell_wwidth = built
 
-        if device:
-            dev = jnp.asarray
-        else:
-            dev = lambda x: x  # noqa: E731 — host-resident build
-        m = SparseMatrix(
-            row_offsets=dev(row_offsets),
-            col_indices=dev(col_indices),
-            values=dev(values),
-            row_ids=dev(row_ids),
-            diag=dev(diag),
-            ell_cols=None if ell_cols is None else dev(ell_cols),
-            ell_vals=None if ell_vals is None else dev(ell_vals),
-            ell_wcols=None if ell_wcols is None else dev(ell_wcols),
-            ell_wvals=None if ell_wvals is None else dev(ell_wvals),
-            ell_wbase=None if ell_wbase is None else dev(ell_wbase),
-            ell_wwidth=ell_wwidth,
-            dia_vals=None if dia_vals is None else dev(dia_vals),
-            dense=None if dense is None else dev(dense),
-            diag_src=None if diag_src is None else dev(diag_src),
-            dia_src=None if dia_src is None else dev(dia_src),
-            ell_src=None if ell_src is None else dev(ell_src),
-            mf_coefs=None if mf_coefs is None else dev(mf_coefs),
-            mf_src=None if mf_src is None else dev(mf_src),
-            n_rows=int(n_rows),
-            n_cols=int(n_cols),
-            block_size=int(b),
-            dia_offsets=dia_offsets,
-            mf_meta=mf_meta,
-            views=views,
-            partition=partition,
-        )
-        if device:
-            from amgx_tpu.core import profiling
-
-            # eager per-matrix upload: counts as one transfer batch
-            # when a setup profile is active (the reference cold-setup
-            # path performs several of these per level; the fast path's
-            # single batched finalize is asserted against this hook)
-            if profiling.active_setup_profile() is not None:
-                n_arr = sum(
-                    x is not None
-                    for x in (
-                        row_offsets, col_indices, values, row_ids,
-                        diag, ell_cols, ell_vals, ell_wcols, ell_wvals,
-                        ell_wbase, dia_vals, dense, diag_src, dia_src,
-                        ell_src, mf_coefs, mf_src,
-                    )
-                )
-                profiling.count_setup_transfer(n_arr)
-        return m
+        return {
+            "arrays": dict(
+                row_offsets=row_offsets,
+                col_indices=col_indices,
+                values=values,
+                row_ids=row_ids,
+                diag=diag,
+                ell_cols=ell_cols,
+                ell_vals=ell_vals,
+                ell_wcols=ell_wcols,
+                ell_wvals=ell_wvals,
+                ell_wbase=ell_wbase,
+                dia_vals=dia_vals,
+                dense=dense,
+                diag_src=diag_src,
+                dia_src=dia_src,
+                ell_src=ell_src,
+                mf_coefs=mf_coefs,
+                mf_src=mf_src,
+            ),
+            "ell_wwidth": ell_wwidth,
+            "dia_offsets": dia_offsets,
+            "mf_meta": mf_meta,
+        }
 
     @staticmethod
     def from_coo(

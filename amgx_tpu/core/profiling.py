@@ -2,12 +2,10 @@
 levelProfile, profile.h phase markers; SURVEY §5.1).
 
 TPU mapping: NVTX ranges become ``jax.profiler.TraceAnnotation`` (host
-trace spans) for API-level calls and ``jax.named_scope`` (HLO op
-metadata, visible in xprof/tensorboard traces) for traced compute;
-the per-level tic/toc map becomes :class:`LevelProfile`, and
-:func:`profile_cycle` measures one V-cycle phase-by-phase the way the
-reference's ``level->Profile.tic("Smoother")`` instrumentation does
-(fixed_cycle.cu:61-110).
+trace spans) for API-level calls and set-up phases, on the same clock
+as the device trace, and ``jax.named_scope`` (HLO op metadata, visible
+in xprof/tensorboard traces) for traced compute; the per-level
+tic/toc map becomes :class:`LevelProfile`.
 """
 
 from __future__ import annotations
@@ -38,41 +36,149 @@ def setup_fastpath_enabled() -> bool:
 # The AMG driver opens a setup_profile_scope around hierarchy
 # construction; coarsening code (amg/classical.py, amg/aggregation.py,
 # amg/device_setup.py) wraps its stages in setup_phase(...) without
-# needing a handle to the solver.  The scope stack is thread-local so
-# concurrent setups (serve compile worker + foreground) never write
-# into each other's profiles.
+# needing a handle to the solver.  Every phase is one span on the
+# profiler's clock (a ``setup:<phase>`` TraceAnnotation) of one kind:
+#
+#   host      numpy/scipy work and the dispatch of device programs
+#             whose results the set-up does not wait for;
+#   device    from a device program's dispatch until its result is
+#             ready, closed at the sync the set-up already makes;
+#   transfer  host<->device copies, each to completion.
+#
+# Each open scope accumulates, on SELF time (a span's time less that
+# of the spans opened inside it, so no second is counted twice), the
+# totals host_s / device_s / transfer_s, JAX's compile time compile_s
+# (taken out of the span open when the compile ran), and
+# unattributed_s: time inside the scope but in no span.  Together
+# they partition the scope's wall time.  Phase keys (``aggregation``,
+# ``rap_execute``, ...) keep their inclusive meaning and go to the
+# innermost scope only; the totals go to every enclosing scope, so a
+# caller's scope around an upload and a solver's setup() sums the
+# nested solvers' scopes too.  The span and scope stacks are
+# thread-local so concurrent setups (serve compile worker +
+# foreground) never write into each other's profiles.
 
 _setup_tls = threading.local()
+
+SPAN_KINDS = ("host", "device", "transfer")
+SETUP_TOTALS = ("host_s", "device_s", "transfer_s", "compile_s",
+                "unattributed_s")
+_KIND_TOTAL = {"host": "host_s", "device": "device_s",
+               "transfer": "transfer_s", None: "unattributed_s"}
+# the events benchmark/run.py CompileClock sums
+_COMPILE_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+))
+_compile_listener = [False]
 
 # module-level transfer/sync accumulators — test-countable the same way
 # serve's _fetch_host/_block_ready hooks are (tests snapshot, run a
 # setup, and assert on the delta).  [batches, arrays, bytes] / [syncs].
 # Lock-guarded: concurrent setups (serve compile worker + foreground)
-# must not lose increments to interleaved read-modify-writes — the
-# exact corruption class the per-call device_setup accumulators fixed.
+# must not lose increments to interleaved read-modify-writes.
 setup_transfer_count = [0, 0, 0]
 setup_sync_count = [0]
 _counter_lock = threading.Lock()
+
+
+class _Frame:
+    """One open span (or scope, ``kind`` None) on this thread."""
+
+    __slots__ = ("kind", "scopes", "t0", "child_s", "compile_s")
+
+    def __init__(self, kind, scopes, t0):
+        self.kind = kind
+        self.scopes = scopes
+        self.t0 = t0
+        self.child_s = 0.0
+        self.compile_s = 0.0
 
 
 def _setup_stack():
     st = getattr(_setup_tls, "stack", None)
     if st is None:
         st = _setup_tls.stack = []
+        _setup_tls.frames = []
+        _setup_tls.compiles = []
+        _setup_tls.level = None
     return st
+
+
+def _on_compile_event(event, duration, **_kw):
+    """Monitoring listener: charge a compile to the innermost frame of
+    the compiling thread.  Events nest (a jit traced inside another's
+    trace reports its own duration), so a later event that encloses
+    earlier ones replaces them and each compile second counts once."""
+    if event not in _COMPILE_EVENTS:
+        return
+    frames = getattr(_setup_tls, "frames", None)
+    if not frames:
+        return
+    start = time.perf_counter() - duration
+    done = _setup_tls.compiles
+    inner = 0.0
+    while done and done[-1][0] >= start:
+        inner += done.pop()[1]
+    done.append((start, duration))
+    frames[-1].compile_s += duration - inner
+
+
+def _watch_compiles():
+    if not _compile_listener[0]:
+        with _counter_lock:
+            if not _compile_listener[0]:
+                jax.monitoring.register_event_duration_secs_listener(
+                    _on_compile_event)
+                _compile_listener[0] = True
+
+
+def _open_frame(kind):
+    scopes = _setup_tls.stack
+    if len(scopes) > 1:  # one dict entered twice accumulates once
+        scopes = list({id(p): p for p in scopes}.values())
+    frame = _Frame(kind, tuple(scopes), time.perf_counter())
+    _setup_tls.frames.append(frame)
+    return frame
+
+
+def _close_frame(frame) -> float:
+    """Pop ``frame``, charge its self time and compile time to its
+    scopes, and return its wall time."""
+    dur = time.perf_counter() - frame.t0
+    frames = _setup_tls.frames
+    frames.pop()
+    if frames:
+        frames[-1].child_s += dur
+    else:
+        _setup_tls.compiles.clear()
+    key = _KIND_TOTAL[frame.kind]
+    self_s = dur - frame.child_s - frame.compile_s
+    for p in frame.scopes:
+        p[key] = p.get(key, 0.0) + self_s
+        p["compile_s"] = p.get("compile_s", 0.0) + frame.compile_s
+    return dur
 
 
 @contextlib.contextmanager
 def setup_profile_scope(profile: dict):
-    """Activate ``profile`` as this thread's setup-phase sink; nested
-    scopes shadow outer ones (a smoother's own AMG setup would profile
-    into its own dict, not its parent's)."""
+    """Activate ``profile`` as this thread's setup-phase sink.  Phase
+    keys go to the innermost scope (a smoother's own AMG setup
+    profiles its phases into its own dict, not its parent's); the
+    five ``SETUP_TOTALS`` go to every open scope, so they cover
+    everything inside, nested scopes included, each second once."""
+    _watch_compiles()
     st = _setup_stack()
     st.append(profile)
+    frame = _open_frame(None)
     try:
         yield profile
     finally:
+        _close_frame(frame)
         st.pop()
+        for k in SETUP_TOTALS:
+            profile.setdefault(k, 0.0)
 
 
 def active_setup_profile() -> dict | None:
@@ -81,30 +187,51 @@ def active_setup_profile() -> dict | None:
 
 
 @contextlib.contextmanager
-def setup_phase(name: str):
-    """Accumulate wall-clock for one setup phase (strength, cf_split,
-    aggregation, interp, rap_plan, rap_execute, transfer, finalize)
-    into the active profile.  No-op outside a scope (and with tracing
-    off), so module-level helpers can be instrumented
-    unconditionally.  When request tracing is on
-    (``AMGX_TPU_TRACE_SAMPLE``), every phase also records a
-    ``setup:<name>`` span into the telemetry span buffer, so setup
-    phases land on the SAME Perfetto timeline as serve spans — one
-    profiling system, not two."""
-    prof = active_setup_profile()
-    tracer = _span_recorder()
-    if prof is None and tracer is None:
-        yield
-        return
-    t0 = time.perf_counter()
+def setup_level(level_id: int):
+    """Tag the spans opened inside with ``level=level_id``."""
+    _setup_stack()
+    outer = _setup_tls.level
+    _setup_tls.level = int(level_id)
     try:
         yield
     finally:
-        t1 = time.perf_counter()
-        if prof is not None:
-            prof[name] = prof.get(name, 0.0) + t1 - t0
-        if tracer is not None:
-            tracer(f"setup:{name}", t0, t1)
+        _setup_tls.level = outer
+
+
+@contextlib.contextmanager
+def setup_phase(name: str, kind: str = "host"):
+    """One setup span of ``kind`` (host, device or transfer; see the
+    comment above): a ``setup:<name>`` TraceAnnotation with ``kind``
+    and, inside a level's build, ``level`` as metadata.  Inside a
+    scope its wall time accumulates into the phase key ``name`` of the
+    innermost scope and its self time into the scopes' totals.  When
+    request tracing is on (``AMGX_TPU_TRACE_SAMPLE``), the interval
+    also lands in the telemetry span buffer, on the same timeline as
+    serve spans.  Outside a scope and with request tracing off it costs
+    one TraceMe check and one clock pair."""
+    st = _setup_stack()
+    level = _setup_tls.level
+    meta = {"kind": kind} if level is None else {"kind": kind,
+                                                 "level": level}
+    tracer = _span_recorder()
+    with jax.profiler.TraceAnnotation(f"setup:{name}", **meta):
+        if not st:
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                if tracer is not None:
+                    tracer(f"setup:{name}", t0, time.perf_counter())
+            return
+        prof = st[-1]
+        frame = _open_frame(kind)
+        try:
+            yield
+        finally:
+            dur = _close_frame(frame)
+            prof[name] = prof.get(name, 0.0) + dur
+            if tracer is not None:
+                tracer(f"setup:{name}", frame.t0, frame.t0 + dur)
 
 
 def _span_recorder():
@@ -126,11 +253,12 @@ def _span_recorder():
 def count_setup_sync(n: int = 1):
     """Record ``n`` device->host synchronizations performed during
     setup (scalar readbacks of the device pipeline) in the module
-    counter.  Per-profile "syncs" attribution stays with the caller's
-    own profile dict (the device pipeline threads one through its
-    build), so this hook never double-counts into the active scope."""
+    counter and in the active profile's ``syncs``."""
     with _counter_lock:
         setup_sync_count[0] += n
+    prof = active_setup_profile()
+    if prof is not None:
+        prof["syncs"] = prof.get("syncs", 0) + n
 
 
 def count_setup_transfer(n_arrays: int, n_bytes: int = 0):
@@ -149,6 +277,23 @@ def count_setup_transfer(n_arrays: int, n_bytes: int = 0):
         )
 
 
+def setup_upload(tree, name: str = "upload"):
+    """``jax.device_put`` of a pytree of host arrays as one ``transfer``
+    span that lasts until the copies have landed."""
+    with setup_phase(name, "transfer"):
+        out = jax.device_put(tree)
+        jax.block_until_ready(out)
+    return out
+
+
+def setup_download(tree, name: str = "download"):
+    """``jax.device_get`` of a pytree of device arrays (numpy leaves
+    pass through) as one ``transfer`` span; the read blocks until the
+    copies are on the host."""
+    with setup_phase(name, "transfer"):
+        return jax.device_get(tree)
+
+
 def setup_transfer(leaves):
     """Ship a list of array leaves host->device as ONE batched
     ``jax.device_put`` (the store-restore lever, store/serialize.py
@@ -157,13 +302,8 @@ def setup_transfer(leaves):
     leaves pass through unchanged inside the same batch."""
     host = [l for l in leaves if isinstance(l, np.ndarray)]
     n_bytes = sum(l.nbytes for l in host)
-    with setup_phase("transfer"):
-        out = jax.device_put(leaves) if leaves else []
-        # device_put returns at dispatch; block so the recorded
-        # transfer phase covers the COPY, not just its enqueue (the
-        # very next setup stage consumes these buffers anyway)
-        jax.block_until_ready(out)
-        count_setup_transfer(len(host), n_bytes)
+    out = setup_upload(leaves, "transfer") if leaves else []
+    count_setup_transfer(len(host), n_bytes)
     return out
 
 
@@ -303,7 +443,7 @@ class LevelProfile:
     iteration" torn-read window the PR 7 audit closed.  Mutate via
     :meth:`phase`/:meth:`add`; read via :meth:`snapshot` (the
     ``times``/``counts`` attributes remain for single-threaded
-    callers, e.g. :func:`profile_cycle`)."""
+    callers)."""
 
     def __init__(self):
         self.times = defaultdict(float)
@@ -339,98 +479,3 @@ class LevelProfile:
                 f"    {k:<30s} {counts[k]:>5d} {times[k]:>12.6f}"
             )
         return "\n".join(lines)
-
-
-def profile_cycle(amg, b, reps: int = 3) -> LevelProfile:
-    """Measure one V-cycle phase-by-phase per level — the
-    observability contract of the reference's per-level profile
-    (VERDICT r1 next-round #10).
-
-    Each phase is jitted once, warmed up (compile excluded), then timed
-    over ``reps`` synchronized executions (``jax.device_get`` of the
-    result — a real round-trip); the recorded time is the per-call
-    mean.  The per-dispatch overhead is part of each phase time — use
-    bench.py's marginal-cost methodology for kernel-level numbers; this
-    tool is for RELATIVE per-level/phase attribution.
-
-    ``amg`` is a set-up AMGSolver; returns a LevelProfile whose keys
-    are 'level{i}/{smooth_pre,residual,restrict,prolong,smooth_post}'
-    and 'coarse/solve'.
-    """
-    import jax.numpy as jnp
-
-    from amgx_tpu.ops.spmv import spmv
-
-    prof = LevelProfile()
-    params = amg.apply_params()
-    level_params, coarse_params = params
-    smooth_fns = [
-        lvl.smoother.make_smooth() if lvl.smoother else None
-        for lvl in amg.levels
-    ]
-    coarse_apply = (
-        amg.coarse_solver.make_apply() if amg.coarse_solver else None
-    )
-
-    def timed(key, fn, *args):
-        out = fn(*args)  # warm-up: trace + compile, result discarded
-        jax.device_get(out)
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            out = fn(*args)
-            jax.device_get(out)
-        dt = (time.perf_counter() - t0) / reps
-        prof.times[key] += dt
-        prof.counts[key] += 1
-        return out
-
-    n_levels = len(amg.levels)
-    bs = [jnp.asarray(b)]
-    xs = []
-    # downward pass
-    for i in range(n_levels - 1):
-        A, P, R, smp = level_params[i]
-        pre, post = amg._level_sweeps(i)
-        x = jnp.zeros_like(bs[i])
-        if pre > 0:
-            x = timed(
-                f"level{i}/smooth_pre",
-                jax.jit(smooth_fns[i], static_argnums=3),
-                smp, bs[i], x, pre,
-            )
-        r = timed(
-            f"level{i}/residual",
-            jax.jit(lambda A, b, x: b - spmv(A, x)),
-            A, bs[i], x,
-        )
-        bc = timed(f"level{i}/restrict", jax.jit(spmv), R, r)
-        xs.append(x)
-        bs.append(bc)
-    # coarsest
-    i = n_levels - 1
-    A, P, R, smp = level_params[i]
-    xc = jnp.zeros_like(bs[i])
-    if coarse_apply is not None:
-        xc = timed(
-            "coarse/solve", jax.jit(coarse_apply), coarse_params, bs[i]
-        )
-    elif smooth_fns[i] is not None:
-        xc = timed(
-            "coarse/smooth",
-            jax.jit(smooth_fns[i], static_argnums=3),
-            smp, bs[i], xc, amg.coarsest_sweeps,
-        )
-    # upward pass
-    for i in range(n_levels - 2, -1, -1):
-        A, P, R, smp = level_params[i]
-        pre, post = amg._level_sweeps(i)
-        corr = timed(f"level{i}/prolong", jax.jit(spmv), P, xc)
-        x = xs[i] + corr
-        if post > 0:
-            x = timed(
-                f"level{i}/smooth_post",
-                jax.jit(smooth_fns[i], static_argnums=3),
-                smp, bs[i], x, post,
-            )
-        xc = x
-    return prof

@@ -47,14 +47,16 @@ def invert_diag(A):
     identity, and any block whose inverse is singular/non-finite also
     falls back to identity — the smoother stays finite on that row
     instead of spraying inf/NaN through every sweep."""
-    d = np.asarray(A.diag)
+    from amgx_tpu.core.profiling import setup_download, setup_upload
+
+    d = setup_download(A.diag)
     if A.block_size == 1:
         with np.errstate(divide="ignore"):
             inv = np.where(d != 0, 1.0 / d, 1.0)
         # numpy promotes extension dtypes (bfloat16) against python
         # floats — the smoother state must stay in the LEVEL dtype or
         # every reduced-precision sweep silently upcasts
-        return jnp.asarray(inv.astype(d.dtype, copy=False))
+        return setup_upload(inv.astype(d.dtype, copy=False))
     b = A.block_size
     if d.dtype.itemsize < 4:
         # LAPACK has no sub-f32 factorizations (and numpy would hand

@@ -34,6 +34,7 @@ import numpy as np
 from amgx_tpu.core import faults
 from amgx_tpu.core.matrix import SparseMatrix
 from amgx_tpu.core.printing import emit
+from amgx_tpu.core.profiling import setup_phase
 from amgx_tpu.core.types import NormType
 
 from amgx_tpu.ops.spmv import spmv
@@ -403,9 +404,10 @@ class Solver:
         if _errors.validation_enabled():
             # typed setup guardrail: NaN/Inf coefficients fail HERE
             # with SetupError, not as a NaN status many layers later
-            _errors.validate_operator(
-                A, where=f"{self.registry_name} setup"
-            )
+            with setup_phase("validate"):
+                _errors.validate_operator(
+                    A, where=f"{self.registry_name} setup"
+                )
         if self.solver_verbose:
             # reference solver.cu:349: dump the solver settings
             emit(
@@ -684,7 +686,17 @@ class Solver:
         scaled/reordered copy), the jitted solve donates it
         (``donate_argnums``) so XLA writes the solution in place.  A
         caller-owned device x0 is never donated — that aliasing caveat
-        is the one documented in doc/SERVING.md."""
+        is the one documented in doc/SERVING.md.
+
+        The call is a host span ``solve`` (``solver`` metadata) on the
+        profiler's clock, with a ``solve:dispatch`` span around the
+        jitted call; nothing is added inside the traced program."""
+        with jax.profiler.TraceAnnotation(
+            "solve", solver=self.registry_name
+        ):
+            return self._solve(b, x0, zero_initial_guess, block)
+
+    def _solve(self, b, x0, zero_initial_guess, block) -> SolveResult:
         if self.A is None:
             raise RuntimeError("solve() before setup()")
         b = jnp.asarray(b)
@@ -716,7 +728,8 @@ class Solver:
             self.last_compile_s = 0.0
         t0 = time.perf_counter()
         self.solve_retries_used = 0
-        res = fn(self.apply_params(), b, x0)
+        with jax.profiler.TraceAnnotation("solve:dispatch"):
+            res = fn(self.apply_params(), b, x0)
         if self.solve_retries > 0:
             res = self._retry_if_failed(res, key, b)
         if self._reorder is not None:

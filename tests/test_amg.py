@@ -385,11 +385,11 @@ def test_geo_aggregation_semicoarsens_anisotropic():
 
 
 def test_profiling_hooks():
-    """Per-level phase timers + named HLO scopes (reference
+    """Named HLO scopes per cycle phase and level (reference
     amgx_timer.h:32-60 nvtxRange/levelProfile; SURVEY §5.1)."""
     import jax
 
-    from amgx_tpu.core.profiling import profile_cycle, trace_range
+    from amgx_tpu.core.profiling import trace_range
 
     A = poisson_3d_7pt(8)
     b = poisson_rhs(A.n_rows)
@@ -398,13 +398,6 @@ def test_profiling_hooks():
     )
     s = create_solver(cfg, "default")
     s.setup(A)
-    prof = profile_cycle(s, b)
-    keys = set(prof.times)
-    assert any(k.endswith("/smooth_pre") for k in keys)
-    assert any(k.endswith("/restrict") for k in keys)
-    assert any(k.endswith("/prolong") for k in keys)
-    assert "coarse/solve" in keys or "coarse/smooth" in keys
-    assert all(v >= 0 for v in prof.times.values())
     # the traced cycle carries named scopes into the HLO metadata
     cyc = s.make_cycle()
     params = s.apply_params()

@@ -322,6 +322,7 @@ def test_device_setup_per_call_profile():
     import scipy.sparse as sps
 
     from amgx_tpu.amg.device_setup import build_classical_level_device
+    from amgx_tpu.core.profiling import setup_profile_scope
 
     cfg = AMGConfig.from_string(
         '{"config_version": 2, "solver": {"scope": "main", '
@@ -331,11 +332,13 @@ def test_device_setup_per_call_profile():
     Asp = poisson_2d_5pt(12).to_scipy().tocsr()
     p1: dict = {}
     p2: dict = {}
-    build_classical_level_device(Asp, cfg, "main", 0, profile=p1)
-    build_classical_level_device(Asp, cfg, "main", 0, profile=p2)
+    for p in (p1, p2):
+        with setup_profile_scope(p):
+            build_classical_level_device(Asp, cfg, "main", 0)
     for p in (p1, p2):
         assert p["syncs"] > 0
-        assert p["host_s"] >= 0.0 and p["device_s"] >= 0.0
+        assert p["host_s"] > 0.0 and p["device_s"] > 0.0
+        assert p["transfer_s"] > 0.0
     # independent accumulation, not a shared running total
     assert p1["syncs"] == p2["syncs"]
 
