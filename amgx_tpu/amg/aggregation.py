@@ -551,19 +551,10 @@ def geo_aggregate(
     semicoarsen along the strong axis.  Coarse aggregates are numbered
     lexicographically on the coarse grid, so bandedness is preserved.
     """
-    dims = [nx, ny, nz]
-    block = list(geo_block_shape(nx, ny, nz, passes, strengths))
-    cdims = [-(-dims[a] // block[a]) for a in range(3)]
-    i = np.arange(nx * ny * nz, dtype=np.int64)
-    ix = i % nx
-    iy = (i // nx) % ny
-    iz = i // (nx * ny)
-    agg = (
-        ix // block[0]
-        + cdims[0] * (iy // block[1])
-        + cdims[0] * cdims[1] * (iz // block[2])
-    )
-    return agg.astype(np.int32)
+    from amgx_tpu.ops.geo_transfer import blocked_map
+
+    block = geo_block_shape(nx, ny, nz, passes, strengths)
+    return blocked_map((nx, ny, nz), block)
 
 
 def select_aggregates(Asp, cfg, scope):
@@ -881,11 +872,14 @@ def _geo_rap_assemble(stacked, grid, block, decs):
 
 
 def build_aggregation_level(Asp, cfg, scope):
-    """Returns (P, R, A_coarse) scipy matrices for one aggregation level
+    """Returns (P, R, A_coarse, geo_info) for one aggregation level
     (reference aggregation_amg_level.cu:238-371 R/P from aggregate map +
-    coarseAGenerator computeAOperator).  Geometric aggregations compute
-    the Galerkin product via dense diagonal reductions
-    (geo_galerkin_dia) instead of sparse-sparse products."""
+    coarseAGenerator computeAOperator): scipy P, R and coarse operator,
+    and the (grid, block) of a geometric aggregation (None otherwise),
+    which lets the level's P and R take the structured-transfer format.
+    Geometric aggregations compute the Galerkin product via dense
+    diagonal reductions (geo_galerkin_dia) instead of sparse-sparse
+    products."""
     # reference coarseAgenerator (coarse_A_generator.cu factory): both
     # registered generators (LOW_DEG hash SpGEMM, GALERKIN cusp product)
     # compute the same R A P; here one device/scipy Galerkin serves both
@@ -926,4 +920,4 @@ def build_aggregation_level(Asp, cfg, scope):
             Ac.sum_duplicates()
             Ac.eliminate_zeros()  # structural parity with the geo path
             Ac.sort_indices()
-    return P, R, Ac
+    return P, R, Ac, geo_info

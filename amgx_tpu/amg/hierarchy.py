@@ -93,7 +93,9 @@ def levels_bitwise_equal(amg_a, amg_b) -> str | None:
                         f"level {la.level_id} {field}.{arr} not "
                         "bitwise-identical"
                     )
-            for accel in ("dia_vals", "ell_vals", "mf_coefs"):
+            if ma.geo_meta != mb.geo_meta:
+                return f"level {la.level_id} {field}.geo_meta mismatch"
+            for accel in ("dia_vals", "ell_vals", "mf_coefs", "geo_w"):
                 va, vb = getattr(ma, accel), getattr(mb, accel)
                 if (va is None) != (vb is None):
                     return (
@@ -228,6 +230,8 @@ class AMGSolver(Solver):
     # setup (reference AMG_Setup::setup, amg.cu:147-418)
 
     def _build_coarse(self, Asp, level_id: int):
+        """``(P, R, Ac, geo_info)`` of the next level: ``geo_info`` is
+        the ``(grid, block)`` of a geometric aggregation, else None."""
         if self.algorithm == "AGGREGATION":
             from amgx_tpu.amg.aggregation import build_aggregation_level
 
@@ -235,7 +239,8 @@ class AMGSolver(Solver):
         if self.algorithm == "ENERGYMIN":
             from amgx_tpu.amg.energymin import build_energymin_level
 
-            return build_energymin_level(Asp, self.cfg, self.scope)
+            return (*build_energymin_level(Asp, self.cfg, self.scope),
+                    None)
         # device-resident classical pipeline (VERDICT r4 #1): strength,
         # PMIS, D1/D2/MULTIPASS and the Galerkin RAP run as XLA
         # programs with scalar-only host syncs; non-covered configs use
@@ -280,7 +285,7 @@ class AMGSolver(Solver):
                     )
                 else:
                     self.level_builders.append("device")
-                    return out
+                    return (*out, None)
             elif explicit_device:
                 import warnings
 
@@ -291,7 +296,8 @@ class AMGSolver(Solver):
         from amgx_tpu.amg.classical import build_classical_level
 
         self.level_builders.append("host")
-        return build_classical_level(Asp, self.cfg, self.scope, level_id)
+        return (*build_classical_level(Asp, self.cfg, self.scope,
+                                       level_id), None)
 
     def _new_smoother(self) -> Solver:
         """Un-set-up smoother instance for this config (the restore
@@ -452,7 +458,7 @@ class AMGSolver(Solver):
             ):
                 break
             with setup_level(lvl.level_id):
-                P, R, Ac = self._build_coarse(Asp, lvl.level_id)
+                P, R, Ac, geo = self._build_coarse(Asp, lvl.level_id)
                 nc = Ac.shape[0]
                 # stall: empty, non-shrinking, or shrinking slower than
                 # coarsen_threshold allows (reference amg.cu:365-370)
@@ -467,11 +473,16 @@ class AMGSolver(Solver):
                     with setup_phase("reorder"):
                         P, R, Ac = reorder_coarse_level(P, R, Ac, dtype)
                 with setup_phase("level_formats"):
+                    # geometric aggregates: P and R take the structured
+                    # grid transfers once verified against the blocked
+                    # map (a reordered P fails the check and keeps ELL)
                     lvl.P = SparseMatrix.from_scipy(
-                        P.astype(dtype, copy=False), device=not defer
+                        P.astype(dtype, copy=False), device=not defer,
+                        geo_transfer=geo,
                     )
                     lvl.R = SparseMatrix.from_scipy(
-                        R.astype(dtype, copy=False), device=not defer
+                        R.astype(dtype, copy=False), device=not defer,
+                        geo_transfer=geo,
                     )
                     Ac = Ac.astype(dtype, copy=False)
                 if self.structure_reuse != 0:
@@ -722,6 +733,13 @@ class AMGSolver(Solver):
                 if not (reuse_smoothers and coarsest.smoother is not None):
                     self._refresh_smoother(coarsest)
 
+        # levels whose P and R both took the structured grid transfers
+        self.setup_profile["geo_transfer_levels"] = sum(
+            lvl.P is not None
+            and lvl.P.has_geo_transfer
+            and lvl.R.has_geo_transfer
+            for lvl in self.levels
+        )
         self._params = self._collect_params()
         # reference solver.cu:541-546: grid stats and vis data print
         # only at verbosity_level > 2

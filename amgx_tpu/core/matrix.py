@@ -132,6 +132,10 @@ class SparseMatrix:
     # replace_values re-derives coefficients per value swap.
     mf_coefs: Optional[jnp.ndarray] = None
     mf_src: Optional[jnp.ndarray] = None
+    # structured grid transfer (ops/geo_transfer.py): P's fine-length
+    # weights when the matrix is a verified geometric-aggregation P or
+    # R = P^T whose values are not all ones; None otherwise
+    geo_w: Optional[jnp.ndarray] = None
 
     n_rows: int = _static_field(default=0)
     n_cols: int = _static_field(default=0)
@@ -140,6 +144,9 @@ class SparseMatrix:
     # static stencil description (ops.stencil.StencilMeta) of the
     # MATRIX_FREE state; None = format not built
     mf_meta: Any = _static_field(default=None)
+    # static grid-transfer description (ops.geo_transfer.GeoTransferMeta)
+    # of a verified geometric-aggregation P or R; None = format not built
+    geo_meta: Any = _static_field(default=None)
     # windowed-ELL column-window width in lanes (static); None = no
     # windowed arrays
     ell_wwidth: Any = _static_field(default=None)
@@ -180,6 +187,10 @@ class SparseMatrix:
     @property
     def has_matrix_free(self) -> bool:
         return self.mf_meta is not None
+
+    @property
+    def has_geo_transfer(self) -> bool:
+        return self.geo_meta is not None
 
     @property
     def is_square(self) -> bool:
@@ -281,6 +292,13 @@ class SparseMatrix:
             new = dataclasses.replace(
                 new, mf_coefs=_gather_src(self.mf_src, values)
             )
+        if self.has_geo_transfer:
+            # P's values are its fine-length weights; R's are the same
+            # weights in coarse-row order
+            w = values
+            if self.geo_meta.transpose:
+                w = jnp.zeros_like(values).at[self.col_indices].set(values)
+            new = dataclasses.replace(new, geo_w=w)
         if self.has_dense:
             d = jnp.zeros_like(self.dense)
             d = d.at[self.row_ids, self.col_indices].add(values)
@@ -311,6 +329,8 @@ class SparseMatrix:
             rep["dia_vals"] = self.dia_vals.astype(dtype)
         if self.has_matrix_free:
             rep["mf_coefs"] = self.mf_coefs.astype(dtype)
+        if self.geo_w is not None:
+            rep["geo_w"] = self.geo_w.astype(dtype)
         if self.has_dense:
             rep["dense"] = self.dense.astype(dtype)
         # structure is unchanged (fingerprint excludes values/dtype);
@@ -336,6 +356,7 @@ class SparseMatrix:
         accel_formats=("dia", "dense", "ell"),
         validate=None,
         device=True,
+        geo_transfer=None,
     ) -> "SparseMatrix":
         """Build from host CSR arrays (also the upload path — reference
         AMGX_matrix_upload_all, amgx_c.h:262-279).
@@ -360,6 +381,11 @@ class SparseMatrix:
         the dominant per-level setup cost the batched finalize
         removes).  Host-resident matrices are construction-time
         intermediates: solve paths expect device leaves.
+
+        ``geo_transfer`` is the ``(grid, block)`` of a geometric
+        aggregation (``select_aggregates``): when the matrix is verified
+        to be that aggregation's P or R (ops/geo_transfer.py) it takes
+        the structured-transfer format instead of DIA/dense/ELL.
         """
         from amgx_tpu.core import errors as _errors
         from amgx_tpu.core.profiling import setup_phase
@@ -385,6 +411,7 @@ class SparseMatrix:
             host = SparseMatrix._host_formats(
                 row_offsets, col_indices, values, n_rows, n_cols,
                 block_size, build_ell, partition, accel_formats,
+                geo_transfer,
             )
         if device:
             with setup_phase("upload", "transfer"):
@@ -403,6 +430,7 @@ class SparseMatrix:
             block_size=int(block_size),
             dia_offsets=host["dia_offsets"],
             mf_meta=host["mf_meta"],
+            geo_meta=host["geo_meta"],
             views=views,
             partition=partition,
         )
@@ -420,7 +448,8 @@ class SparseMatrix:
 
     @staticmethod
     def _host_formats(row_offsets, col_indices, values, n_rows, n_cols,
-                      b, build_ell, partition, accel_formats) -> dict:
+                      b, build_ell, partition, accel_formats,
+                      geo_transfer=None) -> dict:
         """The host arrays of a ``from_csr`` build: CSR, diagonal and
         the acceleration formats each gate admits."""
         if b == 1:
@@ -444,6 +473,19 @@ class SparseMatrix:
                 diag_src, row_ids[hit_idx], hit_idx.astype(np.int32)
             )
             diag_src[diag_src == sentinel] = -1
+
+        geo_meta = geo_w = None
+        if geo_transfer is not None and b == 1 and partition is None:
+            from amgx_tpu.ops.geo_transfer import detect_geo_transfer_np
+
+            det = detect_geo_transfer_np(
+                row_offsets, col_indices, values, n_rows, n_cols,
+                *geo_transfer,
+            )
+            if det is not None:
+                # the grid reductions replace every other format
+                geo_meta, geo_w = det
+                build_ell = False
 
         dia_offsets = dia_vals = dia_src = None
         # build_ell=False opts out of ALL acceleration structures (DIA
@@ -553,10 +595,12 @@ class SparseMatrix:
                 ell_src=ell_src,
                 mf_coefs=mf_coefs,
                 mf_src=mf_src,
+                geo_w=geo_w,
             ),
             "ell_wwidth": ell_wwidth,
             "dia_offsets": dia_offsets,
             "mf_meta": mf_meta,
+            "geo_meta": geo_meta,
         }
 
     @staticmethod

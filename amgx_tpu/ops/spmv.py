@@ -5,6 +5,11 @@ Reference parity: multiply(A, B, C, view) with block-size dispatch
 
 TPU formulation: static-shape, jittable layouts ordered by speed.
 
+  * structured grid transfer (the P and R = P^T of geometric
+    aggregation, verified against the blocked map): box sums and
+    broadcasts over the grid, an MXU product for the x blocks
+    (:mod:`amgx_tpu.ops.geo_transfer`) — the vector streams once, no
+    gather.
   * MATRIX_FREE (verified constant / axis-separable stencils): 3D
     shift+FMA with on-the-fly coefficients (:mod:`amgx_tpu.ops.stencil`,
     Pallas kernel in :mod:`amgx_tpu.ops.pallas_stencil`) — zero O(nnz)
@@ -64,6 +69,10 @@ def spmv(A: SparseMatrix, x: jnp.ndarray, n_rows: int | None = None):
 
 
 def _spmv_scalar(A, x):
+    if A.has_geo_transfer:
+        from amgx_tpu.ops.geo_transfer import geo_transfer_spmv
+
+        return geo_transfer_spmv(A, x)
     if A.has_matrix_free:
         # compact stencil state: coefficients regenerate on the fly,
         # the only O(n) streams are x and y (ops/stencil.py)

@@ -226,6 +226,8 @@ class HierarchyCache:
         seen: set = set()
 
         def _fmt(m: SparseMatrix) -> str:
+            if m.has_geo_transfer:
+                return "GEO_TRANSFER"
             if m.has_matrix_free:
                 return "MATRIX_FREE"
             if m.has_dia:

@@ -41,7 +41,7 @@ _SMAT_ARRAY_FIELDS = (
     "row_offsets", "col_indices", "values", "row_ids", "diag",
     "ell_cols", "ell_vals", "ell_wcols", "ell_wvals", "ell_wbase",
     "dia_vals", "dense", "diag_src", "dia_src", "ell_src",
-    "mf_coefs", "mf_src",
+    "mf_coefs", "mf_src", "geo_w",
 )
 
 
@@ -227,6 +227,19 @@ def _smat_spec(A, rec):
                     }
                 }
                 if A.mf_meta is not None
+                else {}
+            ),
+            # optional key likewise: the structured grid transfer's
+            # static description (its weights persist as "geo_w")
+            **(
+                {
+                    "geo_meta": {
+                        "grid": [int(v) for v in A.geo_meta.grid],
+                        "block": [int(v) for v in A.geo_meta.block],
+                        "transpose": bool(A.geo_meta.transpose),
+                    }
+                }
+                if A.geo_meta is not None
                 else {}
             ),
             "views": views,
@@ -484,8 +497,24 @@ def unflatten(spec, arrays):
                     raise StoreError(
                         f"malformed mf_meta in payload spec: {e}"
                     ) from e
+            geo_meta = None
+            if st.get("geo_meta") is not None:
+                from amgx_tpu.ops.geo_transfer import GeoTransferMeta
+
+                try:
+                    gm = st["geo_meta"]
+                    geo_meta = GeoTransferMeta(
+                        grid=tuple(int(v) for v in gm["grid"]),
+                        block=tuple(int(v) for v in gm["block"]),
+                        transpose=bool(gm["transpose"]),
+                    )
+                except (TypeError, ValueError, KeyError) as e:
+                    raise StoreError(
+                        f"malformed geo_meta in payload spec: {e}"
+                    ) from e
             A = SparseMatrix(
                 mf_meta=mf_meta,
+                geo_meta=geo_meta,
                 n_rows=int(st["n_rows"]),
                 n_cols=int(st["n_cols"]),
                 block_size=int(st["block_size"]),

@@ -345,7 +345,7 @@ def test_structured_aggregation_opt_out():
         ' "solver": "AMG", "selector": "SIZE_2",'
         ' "structured_aggregation": 0}}'
     )
-    P, R, Ac = build_aggregation_level(A, cfg, "main")
+    P, R, Ac, _ = build_aggregation_level(A, cfg, "main")
     # matching-based path still works and coarsens
     assert Ac.shape[0] < A.shape[0]
 

@@ -177,6 +177,42 @@ def test_pcg_jacobi_solve_compiles_at_256cubed(chip, monkeypatch):
     assert total < 16e9, f"{total / 1e9:.2f} GB does not fit a v5e"
 
 
+def test_geo_transfers_compile_at_208cubed(chip):
+    """The fine restriction and prolongation of the 208^3 SIZE_8
+    aggregation hierarchy in the structured-transfer format: box sums
+    and one MXU product each, no gather, and no temporary larger than
+    the fine vector (a minor dimension of 2 would be padded 64x)."""
+    from amgx_tpu.core.matrix import SparseMatrix
+    from amgx_tpu.ops.geo_transfer import GeoTransferMeta
+    from amgx_tpu.ops.spmv import spmv
+
+    n, nc = 208**3, 104**3
+
+    def transfer(transpose):
+        rows, cols = (nc, n) if transpose else (n, nc)
+        return SparseMatrix(
+            row_offsets=chip((rows + 1,), _I32),
+            col_indices=chip((n,), _I32), values=chip((n,), _F32),
+            row_ids=chip((n,), _I32), diag=chip((rows,), _F32),
+            ell_cols=None, ell_vals=None, n_rows=rows, n_cols=cols,
+            geo_meta=GeoTransferMeta((208, 208, 208), (2, 2, 2),
+                                     transpose),
+        )
+
+    R, P = transfer(True), transfer(False)
+    legs = (
+        jax.jit(lambda R, r: spmv(R, r)).lower(R, chip((n,), _F32)),
+        jax.jit(lambda P, x, xc: x + spmv(P, xc)).lower(
+            P, chip((n,), _F32), chip((nc,), _F32)),
+    )
+    for lowered in legs:
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        assert " gather(" not in text
+        assert "convolution(" in text
+        assert compiled.memory_analysis().temp_size_in_bytes <= 4 * n
+
+
 def test_ell_gather_compiles_fast_at_classical_level2(chip):
     """The XLA ELL SpMV at level 2 of the 128^3 classical hierarchy
     (134,483 rows x 82 slots): gathered row-major, (n, w), the TPU

@@ -631,9 +631,16 @@ def test_hierarchy_bytes_by_dtype_telemetry():
     svc.solve_many(systems)
     hb = svc.cache.bytes_by_dtype()
     assert hb.get("float32", 0) > 0
-    # the mixed hierarchy's value mass sits in f32, not f64 (the
-    # template operator itself stays at the upload dtype)
-    assert hb["float32"] > hb.get("float64", 0)
+    # the mixed hierarchy's value mass moves from f64 to f32 (the
+    # template operator itself stays at the upload dtype): against the
+    # same hierarchy at f64, every value byte is there at half width
+    plain = BatchedSolveService(config=_amg_cfg(outer_tol=1e-8),
+                                max_batch=8)
+    plain.solve_many(systems)
+    hb64 = plain.cache.bytes_by_dtype()
+    assert hb64.get("float32", 0) == 0
+    assert 2 * hb["float32"] == hb64["float64"]
+    assert hb.get("float64", 0) < hb64["float64"]
     snap = svc.telemetry_snapshot()
     assert snap["hierarchy_bytes"] == hb
     text = telemetry.get_registry().render_prometheus()
