@@ -716,7 +716,12 @@ class SparseMatrix:
         ).tocsr()
 
     def to_dense(self):
-        return np.asarray(self.to_scipy().todense())
+        sp = self.to_scipy()
+        if sp.dtype.itemsize < 4:
+            # scipy's kernels take no bfloat16: densify in f32 (exact)
+            return np.asarray(sp.astype(np.float32).todense()).astype(
+                sp.dtype)
+        return np.asarray(sp.todense())
 
 
 # ---------------------------------------------------------------------------

@@ -97,13 +97,19 @@ def _spmv_scalar(A, x):
             well_kernel_selected,
         )
 
+        # the unstructured branches carry named scopes so a device
+        # trace tells their ops from the cycle's elementwise work
         if well_kernel_selected(A):
-            return pallas_well_spmv(A, x)
-        return ell_spmv_xla(A.ell_cols, A.ell_vals, x)
-    contrib = A.values * x[A.col_indices]
-    return jax.ops.segment_sum(
-        contrib, A.row_ids, num_segments=A.n_rows, indices_are_sorted=True
-    )
+            with jax.named_scope("spmv_well"):
+                return pallas_well_spmv(A, x)
+        with jax.named_scope("spmv_ell"):
+            return ell_spmv_xla(A.ell_cols, A.ell_vals, x)
+    with jax.named_scope("spmv_csr"):
+        contrib = A.values * x[A.col_indices]
+        return jax.ops.segment_sum(
+            contrib, A.row_ids, num_segments=A.n_rows,
+            indices_are_sorted=True
+        )
 
 
 def ell_spmv_xla(ell_cols, ell_vals, x):
@@ -140,17 +146,21 @@ def dia_spmv_xla(dia_vals, x, offsets, n):
 
 def _spmv_block(A, x2d):
     if A.has_ell:
-        xg = x2d[A.ell_cols]  # (n, w, b)
-        return jnp.einsum(
-            "nwij,nwj->ni", A.ell_vals, xg, preferred_element_type=x2d.dtype
+        with jax.named_scope("spmv_ell"):
+            xg = x2d[A.ell_cols]  # (n, w, b)
+            return jnp.einsum(
+                "nwij,nwj->ni", A.ell_vals, xg,
+                preferred_element_type=x2d.dtype
+            )
+    with jax.named_scope("spmv_csr"):
+        xg = x2d[A.col_indices]  # (nnz, b)
+        contrib = jnp.einsum(
+            "nij,nj->ni", A.values, xg, preferred_element_type=x2d.dtype
         )
-    xg = x2d[A.col_indices]  # (nnz, b)
-    contrib = jnp.einsum(
-        "nij,nj->ni", A.values, xg, preferred_element_type=x2d.dtype
-    )
-    return jax.ops.segment_sum(
-        contrib, A.row_ids, num_segments=A.n_rows, indices_are_sorted=True
-    )
+        return jax.ops.segment_sum(
+            contrib, A.row_ids, num_segments=A.n_rows,
+            indices_are_sorted=True
+        )
 
 
 def multiply(A: SparseMatrix, x, n_rows=None):

@@ -152,7 +152,9 @@ class DenseLUSolver(Solver):
 
         def apply(params, r):
             _, lu, piv = params
-            return jax.scipy.linalg.lu_solve((lu, piv), r)
+            # a sub-f32 level's factors are f32 (_setup_impl): solve
+            # in f32, the cycle casts the correction back
+            return jax.scipy.linalg.lu_solve((lu, piv), r.astype(lu.dtype))
 
         return apply
 
