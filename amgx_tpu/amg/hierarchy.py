@@ -740,6 +740,17 @@ class AMGSolver(Solver):
             and lvl.R.has_geo_transfer
             for lvl in self.levels
         )
+        # A, P and R matrices that took the windowed-ELL arrays (the
+        # kernel on a TPU), and each one's mean touched x rows per
+        # 1024-row tile, keyed "l<level>.<role>"
+        touched = {
+            f"l{lvl.level_id}.{role}": float(np.asarray(M.ell_wcount).mean())
+            for lvl in self.levels
+            for role, M in (("A", lvl.A), ("P", lvl.P), ("R", lvl.R))
+            if M is not None and M.ell_wcount is not None
+        }
+        self.setup_profile["well_matrices"] = len(touched)
+        self.setup_profile["well_touched_rows"] = touched
         self._params = self._collect_params()
         # reference solver.cu:541-546: grid stats and vis data print
         # only at verbosity_level > 2

@@ -106,10 +106,14 @@ class SparseMatrix:
     ell_vals: Optional[jnp.ndarray]
     # Windowed tiled ELL (ops.pallas_well layout) for the Pallas
     # lane-gather SpMV kernel: per-row-tile column windows with local
-    # ids; built on TPU backends when column locality permits.
+    # ids, and the window rows each tile touches (sorted, padded) with
+    # their counts; built on TPU backends when the kernel's estimate
+    # beats the XLA gather (pallas_well.build_windowed_ell).
     ell_wcols: Optional[jnp.ndarray] = None
     ell_wvals: Optional[jnp.ndarray] = None
     ell_wbase: Optional[jnp.ndarray] = None
+    ell_wrows: Optional[jnp.ndarray] = None
+    ell_wcount: Optional[jnp.ndarray] = None
     # DIA structure: dia_vals[k, i] = A[i, i + dia_offsets[k]] (0 outside)
     dia_vals: Optional[jnp.ndarray] = None
     # dense copy for small unstructured matrices (SpMV = MXU matmul)
@@ -546,8 +550,7 @@ class SparseMatrix:
             np.add.at(dense, (row_ids, col_indices), values)
 
         ell_cols = ell_vals = ell_src = None
-        ell_wcols = ell_wvals = ell_wbase = None
-        ell_wwidth = None
+        windowed = None
         if (
             build_ell
             and "ell" in accel_formats
@@ -564,17 +567,14 @@ class SparseMatrix:
                     row_offsets, col_indices, values, n_rows, w, b
                 )
                 if b == 1 and w > 0 and _want_tiled_ell(values.dtype):
-                    # Windowed tiling needs column locality; matrices
-                    # without it (and huge-bandwidth ones) ride the XLA
-                    # gather path.  AMG setup renumbers coarse unknowns
-                    # (RCM) so Galerkin operators qualify.
+                    # the windowed kernel where its estimate beats the
+                    # XLA gather over these ELL arrays; AMG set-up
+                    # renumbers coarse unknowns (RCM) for locality
                     from amgx_tpu.ops.pallas_well import build_windowed_ell
 
-                    built = build_windowed_ell(
+                    windowed = build_windowed_ell(
                         row_offsets, ell_cols, ell_vals
                     )
-                    if built is not None:
-                        ell_wcols, ell_wvals, ell_wbase, ell_wwidth = built
 
         return {
             "arrays": dict(
@@ -585,9 +585,11 @@ class SparseMatrix:
                 diag=diag,
                 ell_cols=ell_cols,
                 ell_vals=ell_vals,
-                ell_wcols=ell_wcols,
-                ell_wvals=ell_wvals,
-                ell_wbase=ell_wbase,
+                ell_wcols=None if windowed is None else windowed.cols,
+                ell_wvals=None if windowed is None else windowed.vals,
+                ell_wbase=None if windowed is None else windowed.bases,
+                ell_wrows=None if windowed is None else windowed.rows,
+                ell_wcount=None if windowed is None else windowed.counts,
                 dia_vals=dia_vals,
                 dense=dense,
                 diag_src=diag_src,
@@ -597,7 +599,7 @@ class SparseMatrix:
                 mf_src=mf_src,
                 geo_w=geo_w,
             ),
-            "ell_wwidth": ell_wwidth,
+            "ell_wwidth": None if windowed is None else windowed.width,
             "dia_offsets": dia_offsets,
             "mf_meta": mf_meta,
             "geo_meta": geo_meta,

@@ -315,7 +315,7 @@ def setup_profile_table(profile: dict) -> str:
         if isinstance(v, float):
             lines.append(f"    setup:{k:<24s} {v:>12.6f} s")
         else:
-            lines.append(f"    setup:{k:<24s} {v:>12}")
+            lines.append(f"    setup:{k:<24s} {v!s:>12}")
     return "\n".join(lines)
 
 

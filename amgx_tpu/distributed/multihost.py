@@ -334,6 +334,7 @@ def assemble_level_sharded(
 
     from amgx_tpu.distributed.partition import (
         part_ell_arrays,
+        pad_touched_rows,
         part_interior_windowed,
         tiled_ell_wanted,
     )
@@ -410,30 +411,35 @@ def assemble_level_sharded(
             )[0],
         )
 
-    # windowed interior tiling: static width W must agree across
-    # shards, so the local widths ride one scalar allgather
-    wwidth = None
+    # windowed interior tiling: the static window width and touched-
+    # row list length must agree across shards, so the local ones ride
+    # one scalar allgather
+    wwidth, wk = None, 1
     if tiled_ell_wanted(dtype):
         for p, part in parts_by_p.items():
             per_dev[p]["wtile"] = part_interior_windowed(
                 part, per_dev[p]["ell_cols"], per_dev[p]["ell_vals"],
                 per_dev[p]["int_mask"], rows_pp, counts[p],
             )
-        widths = comm.allgather(
+        shapes = comm.allgather(
             {
-                p: (-1 if per_dev[p]["wtile"] is None
-                    else int(per_dev[p]["wtile"][3]))
+                p: (None if per_dev[p]["wtile"] is None
+                    else (int(per_dev[p]["wtile"].width),
+                          int(per_dev[p]["wtile"].rows.shape[2])))
                 for p in parts_by_p
             },
             kind="wtile-width",
         )
-        if all(W >= 0 for W in widths):
-            wwidth = int(max(widths))
+        if all(s is not None for s in shapes):
+            wwidth = max(W for W, _ in shapes)
+            wk = max(k for _, k in shapes)
             for p in parts_by_p:
-                tc, tv, bs, _ = per_dev[p]["wtile"]
-                per_dev[p]["ell_wcols"] = tc
-                per_dev[p]["ell_wvals"] = tv
-                per_dev[p]["ell_wbase"] = bs
+                wt = per_dev[p]["wtile"]
+                per_dev[p]["ell_wcols"] = wt.cols
+                per_dev[p]["ell_wvals"] = wt.vals
+                per_dev[p]["ell_wbase"] = wt.bases
+                per_dev[p]["ell_wrows"] = pad_touched_rows(wt.rows, wk)
+                per_dev[p]["ell_wcount"] = wt.counts
 
     # explicit shapes/dtypes so a process holding no parts (its mesh
     # devices all remote) still constructs agreeing global arrays
@@ -450,6 +456,8 @@ def assemble_level_sharded(
         "ell_wcols": ((nt, _SUB, w * _LANE), np.int32),
         "ell_wvals": ((nt, _SUB, w * _LANE), dtype),
         "ell_wbase": ((nt,), np.int32),
+        "ell_wrows": ((nt, 1, wk), np.int32),
+        "ell_wcount": ((nt,), np.int32),
     }
 
     def stack(key):
@@ -482,6 +490,8 @@ def assemble_level_sharded(
         ell_wcols=None if wwidth is None else stack("ell_wcols"),
         ell_wvals=None if wwidth is None else stack("ell_wvals"),
         ell_wbase=None if wwidth is None else stack("ell_wbase"),
+        ell_wrows=None if wwidth is None else stack("ell_wrows"),
+        ell_wcount=None if wwidth is None else stack("ell_wcount"),
         ell_wwidth=wwidth,
         perms=None if dm_plan is None else dm_plan["perms"],
         send_idx_d=(

@@ -91,6 +91,8 @@ class DistributedMatrix:
     ell_wcols: Optional[np.ndarray] = None  # [N, nt, 8, w*128] int32
     ell_wvals: Optional[np.ndarray] = None  # [N, nt, 8, w*128]
     ell_wbase: Optional[np.ndarray] = None  # [N, nt] int32
+    ell_wrows: Optional[np.ndarray] = None  # [N, nt, 1, K] int32
+    ell_wcount: Optional[np.ndarray] = None  # [N, nt] int32
     ell_wwidth: Optional[int] = None  # window lanes (static)
     # row ownership: owner[i] = part owning global row i;
     # local_of[i] = its local slot — identity layout for contiguous
@@ -252,12 +254,11 @@ def part_interior_windowed(
 def _build_interior_windowed(
     parts, ell_cols, ell_vals, int_mask, rows_pp, counts
 ):
-    """Per-shard windowed tiling stacked on the shard axis, or None
-    when any shard's interior columns have no bounded window."""
-    n_parts = ell_cols.shape[0]
+    """Per-shard windowed tiling stacked on the shard axis (one
+    :class:`~amgx_tpu.ops.pallas_well.WindowedEll`), or None when any
+    shard's interior declines the kernel."""
     per = []
-    wmax_lanes = 0
-    for p in range(n_parts):
+    for p in range(ell_cols.shape[0]):
         built = part_interior_windowed(
             parts[p], ell_cols[p], ell_vals[p], int_mask[p], rows_pp,
             counts[p],
@@ -265,11 +266,24 @@ def _build_interior_windowed(
         if built is None:
             return None
         per.append(built)
-        wmax_lanes = max(wmax_lanes, built[3])
-    wcols = np.stack([b[0] for b in per])
-    wvals = np.stack([b[1] for b in per])
-    wbase = np.stack([b[2] for b in per])
-    return wcols, wvals, wbase, int(wmax_lanes)
+    from amgx_tpu.ops.pallas_well import WindowedEll
+
+    k = max(b.rows.shape[2] for b in per)
+    return WindowedEll(
+        cols=np.stack([b.cols for b in per]),
+        vals=np.stack([b.vals for b in per]),
+        bases=np.stack([b.bases for b in per]),
+        width=max(b.width for b in per),
+        rows=np.stack([pad_touched_rows(b.rows, k) for b in per]),
+        counts=np.stack([b.counts for b in per]),
+    )
+
+
+def pad_touched_rows(rows, k):
+    """A shard's ``(nt, 1, K)`` touched-row lists padded to ``k``
+    entries, the static length every shard shares (the kernel walks
+    only each tile's count)."""
+    return np.pad(rows, ((0, 0), (0, 0), (0, k - rows.shape[2])))
 
 
 def tiled_ell_wanted(dtype) -> bool:
@@ -887,18 +901,15 @@ def finalize_partition(
         )
 
     # ---- Pallas windowed tiling of the interior rows (TPU) ----------
-    wcols = wvals = wbase = None
-    wwidth = None
+    wt = None
     if (
         int_mask is not None
         and block_size == 1
         and tiled_ell_wanted(Adtype)
     ):
-        built = _build_interior_windowed(
+        wt = _build_interior_windowed(
             parts, ell_cols, ell_vals, int_mask, rows_pp, counts
         )
-        if built is not None:
-            wcols, wvals, wbase, wwidth = built
 
     return DistributedMatrix(
         n_global=n,
@@ -911,10 +922,12 @@ def finalize_partition(
         int_mask=int_mask,
         own_mask=own_mask,
         bnd_rows=bnd_rows,
-        ell_wcols=wcols,
-        ell_wvals=wvals,
-        ell_wbase=wbase,
-        ell_wwidth=wwidth,
+        ell_wcols=None if wt is None else wt.cols,
+        ell_wvals=None if wt is None else wt.vals,
+        ell_wbase=None if wt is None else wt.bases,
+        ell_wrows=None if wt is None else wt.rows,
+        ell_wcount=None if wt is None else wt.counts,
+        ell_wwidth=None if wt is None else wt.width,
         perms=None if dm is None else dm["perms"],
         send_idx_d=None if dm is None else dm["send_idx_d"],
         halo_dir=None if dm is None else dm["halo_dir"],

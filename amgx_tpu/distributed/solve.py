@@ -74,6 +74,8 @@ def _shard_params(A: DistributedMatrix, cfg=None, scope="default"):
             jnp.asarray(A.ell_wcols),
             jnp.asarray(A.ell_wvals),
             jnp.asarray(A.ell_wbase),
+            jnp.asarray(A.ell_wrows),
+            jnp.asarray(A.ell_wcount),
         )
     if A.uses_ppermute:
         out["ex"] = (
@@ -222,9 +224,10 @@ def make_local_spmv(A: DistributedMatrix, axis):
                 # so the output needs no mask.
                 from amgx_tpu.ops.pallas_well import _pallas_well_spmv
 
-                wc, wv, wb = shard["wtile"]
+                wc, wv, wb, wr, wn = shard["wtile"]
                 yi = _pallas_well_spmv(
-                    wc, wv, wb, x_loc, x_loc.shape[0], A.ell_wwidth
+                    wc, wv, wb, wr, wn, x_loc, x_loc.shape[0],
+                    A.ell_wwidth,
                 )
             else:
                 # XLA interior pass: columns clamped into the local

@@ -1,9 +1,10 @@
 """Bandwidth-reducing unknown renumbering (RCM).
 
-TPU rationale: the windowed Pallas SpMV kernel (ops.pallas_well) needs
-every 1024-row tile's columns inside a bounded window.  Stencil
-matrices have that by construction; unstructured matrices get it from a
-reverse-Cuthill-McKee reordering, which is how this framework answers
+TPU rationale: the windowed Pallas SpMV kernel (ops.pallas_well) costs
+each 1024-row tile a walk over the 128-lane x rows its columns touch.
+Stencil matrices touch few by construction; unstructured matrices get
+there from a reverse-Cuthill-McKee reordering, which is how this
+framework answers
 the reference's cuSPARSE-on-arbitrary-CSR performance
 (/root/reference/src/amgx_cusparse.cu) on gather-hostile hardware.
 
@@ -23,8 +24,9 @@ import numpy as np
 
 from amgx_tpu.core import matrix as _m
 
-# window width (lanes) below which reordering has nothing left to win
-_GOOD_WIDTH = 2048
+# mean x rows a tile's kernel walk touches, below which reordering has
+# nothing left to win
+_GOOD_ROWS = 16
 
 
 def rcm_permutation(sp) -> np.ndarray:
@@ -79,36 +81,23 @@ def reorder_coarse_level(P, R, Ac, dtype):
     return P2, R2, Ac2
 
 
-def _max_tile_span(sp) -> int:
-    """Max raw column span (cmax - cmin + 1) over 1024-row tiles — the
-    alignment-free locality measure the AUTO adoption decision uses
-    (the kernel's W quantizes this up to whole vreg tiles, which would
-    blur genuine locality gains out of a quantized comparison)."""
-    from amgx_tpu.ops.pallas_well import _ROW_TILE
-
-    sp = sp.tocsr()
-    n = sp.shape[0]
-    if sp.nnz == 0:
-        return 0
-    rows = np.repeat(np.arange(n), np.diff(sp.indptr))
-    tiles = rows // _ROW_TILE
-    nt = int(tiles[-1]) + 1
-    cmin = np.full(nt, np.iinfo(np.int64).max)
-    cmax = np.full(nt, -1)
-    np.minimum.at(cmin, tiles, sp.indices)
-    np.maximum.at(cmax, tiles, sp.indices)
-    has = cmax >= 0
-    return int((cmax[has] - cmin[has] + 1).max(initial=0))
+def _walk_rows(A):
+    """The kernel's walk over ``A``: touched x rows summed over its
+    row tiles, or None when ``A`` has no windowed arrays."""
+    if A.ell_wcount is None:
+        return None
+    return int(np.asarray(A.ell_wcount).sum())
 
 
 def maybe_reorder(A, mode: str = "AUTO"):
     """Try an RCM renumbering of ``A``; returns ``(A2, perm)`` with
     ``A2 = A[perm][:, perm]`` or ``(A, None)`` when not worthwhile.
 
-    AUTO adopts the ordering only when the permuted matrix actually
-    gains a fast SpMV structure (windowed ELL or DIA); RCM adopts it
-    whenever the matrix is structurally eligible.  On backends that
-    build no Pallas structures (CPU), AUTO never adopts.
+    AUTO adopts the ordering only when the permuted matrix gains a
+    fast SpMV structure: DIA, or windowed ELL that the stored order
+    lacks or walks at least twice as long.  RCM adopts it whenever the
+    matrix is structurally eligible.  On backends that build no Pallas
+    structures (CPU), AUTO never adopts.
     """
     mode = (mode or "AUTO").upper()
     if mode == "NONE":
@@ -122,13 +111,13 @@ def maybe_reorder(A, mode: str = "AUTO"):
         or A.has_dense
     ):
         return A, None
-    cur_w = A.ell_wwidth  # None when no windowed arrays exist
+    cur = _walk_rows(A)
     if mode == "AUTO":
         if not _m._want_tiled_ell(np.dtype(A.values.dtype)):
             return A, None
-        # gather cost scales with the window width: nothing to gain
-        # once the window is already narrow
-        if cur_w is not None and cur_w <= _GOOD_WIDTH:
+        # the kernel's cost is its walk: nothing to gain once each
+        # tile touches only a few x rows
+        if cur is not None and cur <= _GOOD_ROWS * A.ell_wcount.shape[0]:
             return A, None
     sp = A.to_scipy()
     perm = rcm_permutation(sp)
@@ -136,15 +125,9 @@ def maybe_reorder(A, mode: str = "AUTO"):
     sp2.sort_indices()
     A2 = _m.SparseMatrix.from_scipy(sp2, dtype=np.dtype(A.values.dtype))
     if mode == "AUTO":
-        # compare RAW tile spans, not the vreg-quantized kernel widths:
-        # adopt when the ordering halves the locality measure, or when
-        # it unlocks a fast structure the stored order lacks
+        new = _walk_rows(A2)
         gained = A2.has_dia or (
-            A2.ell_wwidth is not None
-            and (
-                cur_w is None
-                or _max_tile_span(sp2) * 2 <= _max_tile_span(sp)
-            )
+            new is not None and (cur is None or new * 2 <= cur)
         )
         if not gained:
             return A, None

@@ -17,10 +17,14 @@ TPU formulation: static-shape, jittable layouts ordered by speed.
   * DIA (stencil matrices): Pallas shift-FMA kernel
     (:mod:`amgx_tpu.ops.pallas_dia`) with an XLA shift+FMA fallback.
   * dense (small unstructured): one MXU matmul.
-  * windowed ELL (unstructured with column locality — natural or
-    RCM-manufactured, :mod:`amgx_tpu.ops.reorder`): Pallas lane-gather
-    kernel (:mod:`amgx_tpu.ops.pallas_well`); XLA gather fallback over
-    the plain ELL arrays.
+  * windowed ELL (unstructured): Pallas lane-gather kernel
+    (:mod:`amgx_tpu.ops.pallas_well`), whose cost is the touched x rows
+    times the ELL slots of each 1024-row tile, not the window's width;
+    built where that walk is estimated cheaper than the XLA gather.
+    RCM (:mod:`amgx_tpu.ops.reorder`) shortens the walk.
+  * ELL: one XLA gather over the plain ELL arrays, at about the same
+    cost per stored entry whatever the columns (and the windowed
+    matrices' path under ``vmap``).
   * CSR (irregular fallback): gather per-nnz + ``segment_sum`` over
     precomputed sorted row ids.
 

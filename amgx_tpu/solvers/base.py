@@ -668,7 +668,7 @@ class Solver:
         inner = getattr(self, "precond", None)
         if inner is not None and inner is not self:
             for k, v in inner.collect_setup_profile().items():
-                prof[k] = prof.get(k, 0) + v
+                prof[k] = prof[k] + v if k in prof else v
         return prof
 
     def solve(self, b, x0=None, zero_initial_guess=False,

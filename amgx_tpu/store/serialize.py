@@ -40,6 +40,7 @@ SCHEMA_VERSION = 1
 _SMAT_ARRAY_FIELDS = (
     "row_offsets", "col_indices", "values", "row_ids", "diag",
     "ell_cols", "ell_vals", "ell_wcols", "ell_wvals", "ell_wbase",
+    "ell_wrows", "ell_wcount",
     "dia_vals", "dense", "diag_src", "dia_src", "ell_src",
     "mf_coefs", "mf_src", "geo_w",
 )
@@ -467,6 +468,12 @@ def unflatten(spec, arrays):
                     kw[name] = dev_of(fsp)
                 else:
                     kw[name] = rec(fsp)
+            wwidth = st.get("ell_wwidth")
+            if kw.get("ell_wcols") is not None and kw.get("ell_wrows") is None:
+                # a store from before the touched-row lists: the matrix
+                # rides the XLA gather over its plain ELL arrays
+                kw.update(ell_wcols=None, ell_wvals=None, ell_wbase=None)
+                wwidth = None
             views = None
             if st.get("views") is not None:
                 views = {
@@ -523,7 +530,7 @@ def unflatten(spec, arrays):
                     if st.get("dia_offsets") is None
                     else tuple(int(o) for o in st["dia_offsets"])
                 ),
-                ell_wwidth=st.get("ell_wwidth"),
+                ell_wwidth=wwidth,
                 views=views,
                 partition=None,
                 **kw,

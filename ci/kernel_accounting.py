@@ -93,15 +93,16 @@ def well_accounting(n_side: int):
     pos = np.arange(sp.nnz) - sp.indptr[r]
     cols[r, pos] = sp.indices
     vals[r, pos] = sp.data
-    built = pw.build_windowed_ell(sp.indptr, cols, vals)
+    built = pw.tile_windowed_ell(sp.indptr, cols, vals)
     assert built is not None, "no bounded window for this matrix"
-    tc, tv, bs, W = built
+    tc, tv, bs, W = built.cols, built.vals, built.bases, built.width
     nt = tc.shape[0]
     item = 4
     x = np.random.default_rng(0).standard_normal(n).astype(np.float32)
     y = np.asarray(
         pw._pallas_well_spmv(
             jnp.asarray(tc), jnp.asarray(tv), jnp.asarray(bs),
+            jnp.asarray(built.rows), jnp.asarray(built.counts),
             jnp.asarray(x), n, W, interpret=True,
         )
     )

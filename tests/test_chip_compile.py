@@ -103,26 +103,45 @@ def test_stencil_kernel_compiles_at_256cubed(chip):
     assert _custom_calls(compiled) >= 1
 
 
-def test_windowed_ell_kernel_compiles_at_128cubed(chip):
-    """Tiled-ELL shapes of a 128^3 7-point operator: 2048 row tiles of
-    7 slots.  RCM gives that operator a 27,648-lane window, over the
-    16,384-lane cap, so the build declines it (measured, scipy RCM):
-    the largest window the build admits is what is compiled here."""
+def _compile_well(chip, n, w, W, k):
+    """The windowed-ELL kernel at ``n`` rows of ``w`` slots, a ``W``-lane
+    window and ``k``-entry touched-row lists."""
     from amgx_tpu.ops.pallas_well import (
         _LANE,
         _ROW_TILE,
         _SUB,
-        _WMAX_DEFAULT,
         _pallas_well_spmv,
     )
 
-    n, w = 128**3, 7
-    nt = n // _ROW_TILE
-    compiled = _pallas_well_spmv.lower(
+    nt = -(-n // _ROW_TILE)
+    return _pallas_well_spmv.lower(
         chip((nt, _SUB, w * _LANE), _I32), chip((nt, _SUB, w * _LANE), _F32),
-        chip((nt,), _I32), chip((n,), _F32), n, _WMAX_DEFAULT,
+        chip((nt,), _I32), chip((nt, 1, k), _I32), chip((nt,), _I32),
+        chip((n,), _F32), n, W,
     ).compile()
-    assert _custom_calls(compiled) >= 1
+
+
+def test_windowed_ell_kernel_compiles_at_128cubed(chip):
+    """Tiled-ELL shapes of a 128^3 7-point operator: 2048 row tiles of
+    7 slots, the 27,648-lane window RCM gives it (rounded up to whole
+    vreg tiles, 28,672) and at most 227 touched x rows a tile."""
+    assert _custom_calls(_compile_well(chip, 128**3, 7, 28672, 227)) >= 1
+
+
+@pytest.mark.parametrize(
+    "n, w, W, k",
+    [
+        # level 1's A of the classical 128^3 hierarchy (RCM order)
+        (649539, 37, 31744, 186),
+        # the fine level's P of that hierarchy
+        (2097152, 6, 342016, 279),
+    ],
+    ids=["A1", "P0"],
+)
+def test_windowed_ell_kernel_compiles_at_classical_shapes(chip, n, w, W, k):
+    """The classical 128^3 hierarchy's windowed-ELL shapes: level 1's A
+    and the fine P, whose window is the widest the gate admits there."""
+    assert _custom_calls(_compile_well(chip, n, w, W, k)) >= 1
 
 
 def test_pcg_jacobi_solve_compiles_at_256cubed(chip, monkeypatch):

@@ -267,6 +267,9 @@ def test_sharded_partition_windowed_interior(monkeypatch):
     np.testing.assert_array_equal(np.asarray(D.ell_wcols), D_ref.ell_wcols)
     np.testing.assert_allclose(np.asarray(D.ell_wvals), D_ref.ell_wvals)
     np.testing.assert_array_equal(np.asarray(D.ell_wbase), D_ref.ell_wbase)
+    np.testing.assert_array_equal(np.asarray(D.ell_wrows), D_ref.ell_wrows)
+    np.testing.assert_array_equal(np.asarray(D.ell_wcount),
+                                  D_ref.ell_wcount)
 
 
 def test_interior_windowed_arrays(monkeypatch):
@@ -294,6 +297,44 @@ def test_interior_windowed_arrays(monkeypatch):
         )
         yi = np.asarray(_pallas_well_spmv(
             D.ell_wcols[p], D.ell_wvals[p], D.ell_wbase[p],
+            D.ell_wrows[p], D.ell_wcount[p],
             x_loc, D.rows_per_part, D.ell_wwidth, interpret=True,
         ))
         np.testing.assert_allclose(yi, yi_ref, rtol=2e-4, atol=2e-4)
+
+
+def test_interior_windowed_solve_matches_xla(monkeypatch):
+    """The row-sharded Jacobi-PCG with its interior pass on the
+    windowed kernel (interpret mode, taken as on a TPU) gives the x of
+    the XLA interior pass."""
+    import jax
+    from jax.sharding import Mesh
+
+    from amgx_tpu.core import sharding
+    from amgx_tpu.distributed.solve import dist_pcg_jacobi
+    from amgx_tpu.ops import pallas_well
+
+    monkeypatch.setenv("AMGX_TPU_TILED_ELL", "1")
+    sp = poisson_3d_7pt(10, dtype=np.float32).to_scipy().tocsr()
+    D = partition_matrix(sp.astype(np.float32), 4)
+    assert D.ell_wrows is not None
+    mesh = Mesh(np.array(jax.devices()[:4]), ("x",))
+    b = np.random.default_rng(3).standard_normal(sp.shape[0]).astype(
+        np.float32)
+    x_xla, it_xla, _ = dist_pcg_jacobi(D, b, mesh, tol=1e-6)
+    # as on a TPU: the kernel, and shard_map without variance checks
+    # (core/sharding.py)
+    monkeypatch.setattr(pallas_well, "well_kernel_selected", lambda A: True)
+    traced = []
+
+    def kernel(*args, **kw):
+        traced.append(args[5].shape)
+        return well(*args, interpret=True, **kw)
+
+    well = pallas_well._pallas_well_spmv
+    monkeypatch.setattr(pallas_well, "_pallas_well_spmv", kernel)
+    monkeypatch.setattr(sharding, "on_tpu", lambda: True)
+    x_well, it_well, _ = dist_pcg_jacobi(D, b, mesh, tol=1e-6)
+    assert traced  # the interior pass ran on the kernel
+    assert it_well == it_xla
+    np.testing.assert_allclose(x_well, x_xla, rtol=1e-5, atol=1e-6)

@@ -49,7 +49,8 @@ def test_would_build_dia():
 def test_maybe_reorder_adopts_on_gain(tiled_env):
     m = _scrambled_banded(6000, 4, 200)
     A = SparseMatrix.from_scipy(m, dtype=np.float32)
-    # scrambled: window spans everything (n <= wmax so it still builds)
+    # scrambled: the window spans everything, yet the walk still beats
+    # the gather at this size, so it builds
     assert A.ell_wwidth is not None and A.ell_wwidth >= 4096
     A2, perm = ro.maybe_reorder(A, "AUTO")
     assert perm is not None
@@ -64,9 +65,10 @@ def test_maybe_reorder_adopts_on_gain(tiled_env):
 
 
 def test_maybe_reorder_adopts_above_wmax(tiled_env):
-    """Above the window cap the scrambled matrix gets NO windowed arrays;
-    RCM restores them."""
-    m = _scrambled_banded(20000, 4, 300, seed=5)
+    """Scrambled, each tile's columns touch nearly every x row of its
+    window, a walk above the gate's walk-versus-gather limit: NO
+    windowed arrays.  RCM restores them."""
+    m = _scrambled_banded(200000, 4, 300, seed=5)
     A = SparseMatrix.from_scipy(m, dtype=np.float32)
     assert A.ell_wcols is None
     A2, perm = ro.maybe_reorder(A, "AUTO")

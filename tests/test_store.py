@@ -262,6 +262,66 @@ def test_scaled_reordered_solver_roundtrip(tmp_path):
     assert np.array_equal(np.asarray(res2.x), np.asarray(res1.x))
 
 
+def _windowed_matrix(monkeypatch):
+    """An unstructured (ELL, not DIA) matrix that takes the windowed
+    arrays: the backend gate forced as on a TPU."""
+    import scipy.sparse as sps
+
+    from amgx_tpu.core.matrix import SparseMatrix
+
+    monkeypatch.setenv("AMGX_TPU_TILED_ELL", "1")
+    n = 6000
+    rng = np.random.default_rng(21)
+    r = np.repeat(np.arange(n), 4)
+    c = np.abs(r + rng.integers(-300, 301, r.shape))
+    c = np.where(c >= n, 2 * (n - 1) - c, c)  # reflected at the ends
+    m = sps.coo_matrix((rng.standard_normal(r.shape) * 0.1, (r, c)),
+                       shape=(n, n)).tocsr()
+    m = (m + m.T + sps.eye_array(n) * 8.0).tocsr()
+    m.sort_indices()
+    A = SparseMatrix.from_scipy(m, dtype=np.float32)
+    assert A.ell_wrows is not None and not A.has_dia
+    return A
+
+
+def test_windowed_ell_roundtrip(tmp_path, monkeypatch):
+    """The windowed-ELL arrays, touched-row lists and counts included,
+    restore bitwise, and the restored solver solves identically."""
+    A = _windowed_matrix(monkeypatch)
+    b = np.random.default_rng(22).standard_normal(A.n_rows).astype(
+        np.float32)
+    s = _setup_solver(JAC_PCG, A)
+    res1 = s.solve(b)
+    path = tmp_path / "windowed.npz"
+    s.save_setup(path)
+    s2 = Solver.load_setup(path)
+    assert s2.A.ell_wwidth == s.A.ell_wwidth
+    for f in ("ell_wcols", "ell_wvals", "ell_wbase", "ell_wrows",
+              "ell_wcount"):
+        assert np.array_equal(np.asarray(getattr(s.A, f)),
+                              np.asarray(getattr(s2.A, f))), f
+    res2 = s2.solve(b)
+    assert int(res2.iters) == int(res1.iters)
+    assert np.array_equal(np.asarray(res2.x), np.asarray(res1.x))
+
+
+def test_windowed_store_without_touched_rows_loads(monkeypatch):
+    """A store written before the touched-row lists existed loads: the
+    matrix drops its windowed arrays and rides the plain ELL arrays."""
+    A = _windowed_matrix(monkeypatch)
+    spec, arrays = ser.flatten(A)
+    spec = json.loads(json.dumps(spec))
+    fields = (spec["n"] if spec["t"] == "def" else spec)["fields"]
+    del fields["ell_wrows"], fields["ell_wcount"]
+    B = ser.unflatten(spec, ser.materialize(arrays))
+    assert B.ell_wcols is None and B.ell_wwidth is None
+    assert B.has_ell
+    x = np.random.default_rng(23).standard_normal(A.n_rows).astype(
+        np.float32)
+    np.testing.assert_allclose(np.asarray(B.to_scipy() @ x),
+                               np.asarray(A.to_scipy() @ x), rtol=1e-6)
+
+
 def test_load_missing_or_not_a_payload(tmp_path):
     with pytest.raises(StoreError):
         Solver.load_setup(tmp_path / "nope.npz")
