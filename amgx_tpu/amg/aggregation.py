@@ -17,7 +17,6 @@ strategy — one scipy product here).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +25,6 @@ import scipy.sparse as sps
 
 from amgx_tpu.core.profiling import (
     setup_download,
-    setup_fastpath_enabled,
     setup_phase,
     setup_upload,
 )
@@ -63,20 +61,14 @@ def _first_per_row(rows_sorted, n):
     """Index of the first occurrence of each row id in a row-sorted array;
     -1 for absent rows."""
     first = np.full(n, -1, dtype=np.int64)
-    if setup_fastpath_enabled():
-        # the input is row-sorted, so first occurrences are exactly the
-        # boundary positions — an O(nnz) flag diff instead of the
-        # np.unique sort the matcher used to pay PER ROUND
-        if rows_sorted.shape[0]:
-            mask = np.empty(rows_sorted.shape[0], dtype=bool)
-            mask[0] = True
-            np.not_equal(rows_sorted[1:], rows_sorted[:-1],
-                         out=mask[1:])
-            idx = np.nonzero(mask)[0]
-            first[rows_sorted[idx]] = idx
-        return first
-    uniq, idx = np.unique(rows_sorted, return_index=True)
-    first[uniq] = idx
+    # the input is row-sorted, so first occurrences are exactly the
+    # boundary positions — an O(nnz) flag diff, not a unique sort
+    if rows_sorted.shape[0]:
+        mask = np.empty(rows_sorted.shape[0], dtype=bool)
+        mask[0] = True
+        np.not_equal(rows_sorted[1:], rows_sorted[:-1], out=mask[1:])
+        idx = np.nonzero(mask)[0]
+        first[rows_sorted[idx]] = idx
     return first
 
 
@@ -152,21 +144,8 @@ def _device_matching_wanted() -> bool:
     """Backend gate for the XLA matcher: accelerators only.  On the
     CPU backend the "device" is the same cores the numpy rounds use,
     so the XLA handshake buys nothing at steady state and its first
-    compile (~0.7-1.4 s measured) dominates a cold setup — exactly the
-    mid-setup device ping-pong the host-resident fast path removes.
-    ``AMGX_TPU_DEVICE_MATCH`` overrides either way (``0`` disables,
-    anything else enables — same parse as AMGX_TPU_SETUP_FASTPATH);
-    the reference path (AMGX_TPU_SETUP_FASTPATH=0) keeps the old
-    size-only gate."""
-    env = os.environ.get("AMGX_TPU_DEVICE_MATCH")
-    if env is not None:
-        return env != "0"
-    if not setup_fastpath_enabled():
-        return True
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover - uninitialized backend
-        return True
+    compile (~0.7-1.4 s measured on the CPU) dominates a cold setup."""
+    return jax.default_backend() != "cpu"
 
 
 def _edge_jitter(r, c, n):
@@ -432,10 +411,7 @@ def stencil_offsets(Asp: sps.csr_matrix, max_diags: int = 64,
             return (None, None) if return_diffs else None
     # int32 diff arithmetic when both dimensions fit: the unique sort
     # runs ~2x faster and the offsets themselves are tiny either way
-    use32 = (
-        setup_fastpath_enabled()
-        and max(Asp.shape) < np.iinfo(np.int32).max
-    )
+    use32 = max(Asp.shape) < np.iinfo(np.int32).max
     diffs = _col_diffs(Asp, np.int32 if use32 else np.int64)
     offs = np.unique(diffs)
     if offs.size > max_diags:

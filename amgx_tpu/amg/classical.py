@@ -17,11 +17,11 @@ import warnings
 import numpy as np
 import scipy.sparse as sps
 
-from amgx_tpu.core.profiling import setup_fastpath_enabled, setup_phase
+from amgx_tpu.core.profiling import setup_phase
 
 
 # ----------------------------------------------------------------------
-# vectorized per-row reductions (the cold-setup fast path)
+# vectorized per-row reductions
 #
 # ``np.ufunc.at`` is the single hottest line of classical setup (its
 # unbuffered per-element loop runs at Python-adjacent speed); these
@@ -29,25 +29,20 @@ from amgx_tpu.core.profiling import setup_fastpath_enabled, setup_phase
 #
 #   * sums: ``np.bincount(weights=...)`` accumulates sequentially in
 #     element order into an f64 accumulator — the exact operation
-#     ``np.add.at`` performs on a zeroed f64 array (asserted by the
-#     fast-vs-reference parity suite, tests/test_setup_fastpath.py).
+#     ``np.add.at`` performs on a zeroed f64 array (asserted by
+#     tests/test_setup_fastpath.py::test_row_reductions_bitwise).
 #   * maxima: max is exactly associative (no rounding), so
 #     ``np.maximum.reduceat`` row segments equal the sequential
 #     ``np.maximum.at`` accumulation for any grouping; casts commute
 #     with max (monotone), so reducing in the value dtype and casting
 #     the row result equals casting every element first.
-#
-# AMGX_TPU_SETUP_FASTPATH=0 routes back to the ufunc.at reference
-# forms (old-vs-new benchmarking, ci/setup_bench.py).
 
 
 def _row_sum(row_ids, weights, n):
     """Per-row sums grouped by ``row_ids`` — bitwise-identical to
     ``np.add.at`` on ``np.zeros(n)``."""
-    if not setup_fastpath_enabled() or np.iscomplexobj(weights):
-        out = np.zeros(
-            n, dtype=weights.dtype if np.iscomplexobj(weights) else None
-        )
+    if np.iscomplexobj(weights):
+        out = np.zeros(n, dtype=weights.dtype)
         np.add.at(out, row_ids, weights)
         return out
     return np.bincount(row_ids, weights=weights, minlength=n)
@@ -61,7 +56,7 @@ def _row_max(vals, indptr, row_ids, init, out_dtype=None):
     n = indptr.shape[0] - 1
     if out_dtype is None:
         out_dtype = vals.dtype
-    if not setup_fastpath_enabled() or vals.shape[0] == 0:
+    if vals.shape[0] == 0:
         out = np.full(n, init, dtype=out_dtype)
         np.maximum.at(out, row_ids, vals)
         return out
@@ -263,7 +258,6 @@ def pmis_select(S: sps.csr_matrix, seed: int = 0) -> np.ndarray:
     state[iso] = 1  # isolated points must be coarse (nothing to interp from)
     coo = Ssym.tocoo()
     coo_row, coo_col = coo.row, coo.col
-    fast = setup_fastpath_enabled()
     for _ in range(200):
         und = state == 0
         if not und.any():
@@ -271,17 +265,12 @@ def pmis_select(S: sps.csr_matrix, seed: int = 0) -> np.ndarray:
         # local max among undecided neighbours
         wu = np.where(und, w, -1.0)
         act = und[coo_row] & und[coo_col]
-        if fast:
-            # row-segmented reduceat over -1-filled inactive slots:
-            # identical to the maximum.at accumulation (w >= 0, so the
-            # -1.0 fill never wins over an active neighbour)
-            nbmax = _row_max(
-                np.where(act, wu[coo_col], -1.0), Ssym.indptr,
-                coo_row, -1.0,
-            )
-        else:
-            nbmax = np.full(n, -1.0)
-            np.maximum.at(nbmax, coo_row[act], wu[coo_col[act]])
+        # row-segmented reduceat over -1-filled inactive slots:
+        # identical to the maximum.at accumulation (w >= 0, so the
+        # -1.0 fill never wins over an active neighbour)
+        nbmax = _row_max(
+            np.where(act, wu[coo_col], -1.0), Ssym.indptr, coo_row, -1.0,
+        )
         new_c = und & (wu > nbmax)
         state[new_c] = 1
         # fine: undecided with a C neighbour
@@ -740,16 +729,13 @@ def build_classical_level(Asp, cfg, scope, level_id: int = 0):
     ).upper()
 
     # per-A-entry strong flags ride along from the strength stage when
-    # it knows them (fast path: saves the D1 interpolator's membership
-    # re-derivation, a full extra pass over A's pattern)
+    # it knows them (saves the D1 interpolator's membership
+    # re-derivation, a full extra pass over A's pattern); AFFINITY
+    # strength returns none
     strong_flag = None
-    fast = setup_fastpath_enabled()
     with setup_phase("strength"):
         if strength == "ALL":
-            if fast:
-                S, strong_flag = strength_all(Asp, return_flags=True)
-            else:
-                S = strength_all(Asp)
+            S, strong_flag = strength_all(Asp, return_flags=True)
         elif strength == "AFFINITY":
             S = strength_affinity(
                 Asp,
@@ -758,12 +744,9 @@ def build_classical_level(Asp, cfg, scope, level_id: int = 0):
                 n_iters=int(cfg.get("affinity_iterations", scope)),
             )
         else:  # AHAT default
-            if fast:
-                S, strong_flag = strength_ahat(
-                    Asp, theta, max_row_sum, return_flags=True
-                )
-            else:
-                S = strength_ahat(Asp, theta, max_row_sum)
+            S, strong_flag = strength_ahat(
+                Asp, theta, max_row_sum, return_flags=True
+            )
 
     aggressive = (
         level_id < aggressive_levels

@@ -20,12 +20,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-import scipy.sparse as sps
 
 from amgx_tpu.core.matrix import SparseMatrix
 from amgx_tpu.core.profiling import (
     named_scope,
-    setup_fastpath_enabled,
     setup_level,
     setup_phase,
     setup_profile_scope,
@@ -67,10 +65,9 @@ def levels_bitwise_equal(amg_a, amg_b) -> str | None:
     values, and rebuilt acceleration structures (DIA/ELL values).
 
     Returns None when equal, else a short human-readable mismatch
-    description.  This is the single parity contract shared by the
-    fast-vs-reference gates (ci/setup_bench.py and
-    tests/test_setup_fastpath.py) — extend it HERE so both stay in
-    lockstep."""
+    description.  This is the hierarchy comparison for store
+    round-trips: a hierarchy restored from a saved set-up must equal
+    the one that was saved."""
     if len(amg_a.levels) != len(amg_b.levels):
         return (
             f"level count {len(amg_a.levels)} != {len(amg_b.levels)}"
@@ -245,8 +242,9 @@ class AMGSolver(Solver):
         # PMIS, D1/D2/MULTIPASS and the Galerkin RAP run as XLA
         # programs with scalar-only host syncs; non-covered configs use
         # the host path.  AUTO is backend-aware: on an accelerator the
-        # pipeline keeps setup off the host (measured host share 1.7%
-        # at 96^3); on the CPU backend "device" is the same core the
+        # pipeline keeps setup off the host (on a TPU v5e at 128^3 it
+        # took 167.6 s against the host path's 42.2 s: PERF.md, Open
+        # questions); on the CPU backend "device" is the same core the
         # scipy path uses, minus nothing, plus per-level XLA compiles —
         # scipy wins there (26 s vs 168 s at 96^3, ci/setup_profile.py)
         loc = str(self.cfg.get("setup_location", self.scope)).upper()
@@ -347,13 +345,13 @@ class AMGSolver(Solver):
             return ("matrix_free", "dia", "dense", "ell")
         return ("dia", "dense", "ell")
 
-    def _maybe_matrix_free(self, A: SparseMatrix, device: bool):
+    def _maybe_matrix_free(self, A: SparseMatrix):
         """Rebuild the finest operator with the MATRIX_FREE format when
         the knob is on and detection succeeds; returns the ORIGINAL
         object (identity, memos intact) otherwise.  User uploads build
         with the default formats, so the upgrade re-runs ``from_csr``
-        over the host CSR triple — host-resident on the fast path so
-        the compact state rides the one batched finalize transfer."""
+        over the host CSR triple — host-resident so the compact state
+        rides the one batched finalize transfer."""
         if (
             not self.matrix_free
             or A.block_size != 1
@@ -370,7 +368,7 @@ class AMGSolver(Solver):
             views=A.views,
             accel_formats=self._accel_formats(),
             validate=False,
-            device=device,
+            device=False,
         )
         return new if new.has_matrix_free else A
 
@@ -380,24 +378,17 @@ class AMGSolver(Solver):
         self.setup_profile = {}
         self.level_builders = []
         with setup_profile_scope(self.setup_profile):
-            # block systems: the scalar expansion is host-resident on
-            # the fast path — as levels[0].A it rides the batched
-            # finalize transfer, keeping ≤1 batch per cold setup
-            A = scalarized(A, "AMG",
-                           device=not setup_fastpath_enabled())
-            A = self._maybe_matrix_free(
-                A, device=not setup_fastpath_enabled()
-            )
+            # block systems: the scalar expansion is host-resident —
+            # as levels[0].A it rides the batched finalize transfer,
+            # keeping ≤1 batch per cold setup
+            A = scalarized(A, "AMG", device=False)
+            A = self._maybe_matrix_free(A)
             self.levels = [AMGLevel(A, 0)]
-            # fast path: read the finest operator back through the
+            # read the finest operator back through the
             # construction-time host memo instead of a device->host
-            # download (the first of the ping-pongs cold setup used to
-            # pay); the reference path keeps the download
+            # download
             with setup_phase("host_csr", "transfer"):
-                Asp = (
-                    A.host_csr() if setup_fastpath_enabled()
-                    else A.to_scipy()
-                )
+                Asp = A.host_csr()
             self._coarsen_from(Asp)
             self._finalize_setup()
             # the finest operator's lazy host memo served the
@@ -433,15 +424,12 @@ class AMGSolver(Solver):
         """Extend ``self.levels`` by coarsening from the last level
         (whose host CSR is ``Asp``) until a stop condition hits.
 
-        Fast path (AMGX_TPU_SETUP_FASTPATH, default on): every matrix
-        this loop builds is HOST-RESIDENT (``device=False``) — the
-        whole coarsening chain strength -> select -> P -> Galerkin
-        stays in numpy, and ``_finalize_setup`` ships the finished
-        hierarchy to the device in one batched transfer
-        (``_upload_levels``).  The reference path uploads each level's
-        P/R/Ac eagerly as before."""
+        Every matrix this loop builds is HOST-RESIDENT
+        (``device=False``) — the whole coarsening chain strength ->
+        select -> P -> Galerkin stays in numpy, and ``_finalize_setup``
+        ships the finished hierarchy to the device in one batched
+        transfer (``_upload_levels``)."""
         self.setup_stats["coarsen_calls"] += 1
-        defer = setup_fastpath_enabled()
         # reference amg.cu:207-230: when the coarse solver is dense LU,
         # coarsening stops once the level fits the dense trigger size
         coarse_name, _ = self.cfg.get_scoped("coarse_solver", self.scope)
@@ -477,26 +465,24 @@ class AMGSolver(Solver):
                     # grid transfers once verified against the blocked
                     # map (a reordered P fails the check and keeps ELL)
                     lvl.P = SparseMatrix.from_scipy(
-                        P.astype(dtype, copy=False), device=not defer,
+                        P.astype(dtype, copy=False), device=False,
                         geo_transfer=geo,
                     )
                     lvl.R = SparseMatrix.from_scipy(
-                        R.astype(dtype, copy=False), device=not defer,
+                        R.astype(dtype, copy=False), device=False,
                         geo_transfer=geo,
                     )
                     Ac = Ac.astype(dtype, copy=False)
                 if self.structure_reuse != 0:
                     with setup_phase("rap_plan"):
-                        lvl.rap_plan = self._try_plan_rap(
-                            R, Asp, P, Ac, device=not defer
-                        )
+                        lvl.rap_plan = self._try_plan_rap(R, Asp, P, Ac)
                 with setup_phase("level_formats"):
                     # Galerkin products of constant stencils on divisible
                     # grids stay constant stencils, so the matrix-free format
                     # propagates down the hierarchy (each level re-verified
                     # independently)
                     Ac_dev = SparseMatrix.from_scipy(
-                        Ac, device=not defer,
+                        Ac, device=False,
                         accel_formats=self._accel_formats(),
                     )
                 self.levels.append(AMGLevel(Ac_dev, len(self.levels)))
@@ -504,7 +490,7 @@ class AMGSolver(Solver):
                 Asp = Ac
 
     @staticmethod
-    def _try_plan_rap(R, Asp, P, Ac, device: bool = True):
+    def _try_plan_rap(R, Asp, P, Ac):
         """Numeric-Galerkin plan for structure reuse, or None when the
         stored coarse pattern doesn't cover the product (truncation,
         geometric dense-reduction with dropped entries)."""
@@ -514,7 +500,7 @@ class AMGSolver(Solver):
             Acc = Ac.tocsr().copy()
             Acc.sort_indices()
             return plan_rap(R.tocsr(), Asp.tocsr(), P.tocsr(), Acc,
-                            device=device)
+                            device=False)
         except ValueError:
             return None
 

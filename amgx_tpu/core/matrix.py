@@ -442,9 +442,8 @@ class SparseMatrix:
             from amgx_tpu.core import profiling
 
             # eager per-matrix upload: counts as one transfer batch
-            # when a setup profile is active (the reference cold-setup
-            # path performs several of these per level; the fast path's
-            # single batched finalize is asserted against this hook)
+            # when a setup profile is active (the AMG set-up's single
+            # batched finalize is asserted against this hook)
             if profiling.active_setup_profile() is not None:
                 n_arr = sum(v is not None for v in arrays.values())
                 profiling.count_setup_transfer(n_arr)

@@ -20,16 +20,6 @@ import jax
 import numpy as np
 
 
-def setup_fastpath_enabled() -> bool:
-    """Cold-setup fast path (host-resident coarsening + batched
-    finalize transfer): ON by default; ``AMGX_TPU_SETUP_FASTPATH=0``
-    selects the reference path (eager per-array uploads, ufunc.at row
-    reductions) — kept for parity testing and old-vs-new benchmarking
-    (ci/setup_bench.py).  Read per call so tests/benches can toggle it
-    mid-process."""
-    return os.environ.get("AMGX_TPU_SETUP_FASTPATH", "1") != "0"
-
-
 # ----------------------------------------------------------------------
 # setup-phase profiling (the cold-setup observability surface)
 #
@@ -263,8 +253,8 @@ def count_setup_sync(n: int = 1):
 
 def count_setup_transfer(n_arrays: int, n_bytes: int = 0):
     """Record one host->device transfer BATCH of ``n_arrays`` arrays.
-    The fast path performs exactly one per hierarchy (the batched
-    finalize); the reference path counts one per from_csr upload."""
+    AMG set-up performs exactly one per hierarchy (the batched
+    finalize); an eager ``from_csr`` upload counts one of its own."""
     with _counter_lock:
         setup_transfer_count[0] += 1
         setup_transfer_count[1] += int(n_arrays)

@@ -20,7 +20,7 @@ def scalarized(A, solver_name: str, device: bool = True):
     (e.g. block DILU) differ from their scalar expansions; native block
     paths are future work.  Vectors are flat (n*b,) either way, so no
     caller-visible change.  ``device=False`` builds the expansion
-    host-resident (the AMG fast path defers it to the batched finalize
+    host-resident (the AMG set-up defers it to the batched finalize
     transfer, preserving the one-batch-per-setup invariant)."""
     if A.block_size == 1:
         return A

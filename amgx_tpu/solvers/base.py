@@ -663,7 +663,7 @@ class Solver:
         keys: strength/cf_split/aggregation/interp/rap_plan/
         rap_execute/transfer/finalize/... ) of this solver and any
         nested preconditioner — the dict behind ``obtain_timings``'s
-        ``setup:<phase>`` lines and bench.py's setup split."""
+        ``setup:<phase>`` lines."""
         prof = dict(getattr(self, "setup_profile", None) or {})
         inner = getattr(self, "precond", None)
         if inner is not None and inner is not self:

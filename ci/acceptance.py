@@ -5,7 +5,7 @@ the current backend and writes ACCEPTANCE.md with the iteration counts
 and residual-rate table — the comparison discipline BASELINE.md:33-35
 demands (iteration parity before wall-clock).  Sizes marked (reduced)
 are scaled down from the official problem for CPU/virtual-mesh runs;
-bench.py covers full-scale numbers on TPU hardware.
+chip numbers come from the benchmark (``python3 -m benchmark.run``).
 
 Usage:  python ci/acceptance.py [out.md]
 """

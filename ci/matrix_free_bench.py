@@ -8,9 +8,9 @@ One JSON line (the ci/ contract) and a non-zero exit when:
   apply by >= 1.3x as a GEOMEAN over the best 3 of 5 interleaved
   attempts (the worst attempts measure scheduler noise, not the
   format).
-  Marginal/chained timing (two dependent-chain lengths, like
-  bench.py) — single-call timing measures dispatch overhead, not the
-  memory traffic this format removes;
+  Marginal/chained timing (two dependent-chain lengths) —
+  single-call timing measures dispatch overhead, not the memory
+  traffic this format removes;
 * **solve speedup** — the full matrix-free AMG solve (fusion off —
   fusion is accounted separately below; it trades CPU time for pass
   structure) fails to beat the DIA solve by >= 1.3x per iteration at

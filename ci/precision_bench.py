@@ -110,7 +110,7 @@ def _rel_residual(sp, b, res):
     )
 
 
-def run(small=False):
+def run():
     import numpy as np
 
     from amgx_tpu.core.matrix import SparseMatrix
@@ -121,7 +121,7 @@ def run(small=False):
     rng = np.random.default_rng(0)
 
     # ---- (a) retired-iteration parity at unchanged tolerance --------
-    side = 32 if small else 48
+    side = 48
     sp = poisson_scipy((side, side)).tocsr()
     sp.sort_indices()
     b = rng.standard_normal(sp.shape[0])
@@ -183,7 +183,7 @@ def run(small=False):
             )
 
     # ---- (b) coarse-setup-time + store-bytes reduction --------------
-    side2 = 64 if small else 96
+    side2 = 96
     sp2 = poisson_scipy((side2, side2)).tocsr()
     sp2.sort_indices()
     b2 = rng.standard_normal(sp2.shape[0])
@@ -216,11 +216,7 @@ def run(small=False):
     t_dense = coarse["DENSE_LU_SOLVER"]["coarse_factor_s"]
     t_inx = coarse["INEXACT"]["coarse_factor_s"]
     time_ratio = t_dense / max(t_inx, 1e-9)
-    # the time gate needs the O(n^3) term to dominate: at the reduced
-    # --small size the INEXACT side's one-off spectral-estimate
-    # compile outweighs a ~1.5k-row factorization, so small mode
-    # reports the ratio but gates only the store bytes
-    if not small and time_ratio < COARSE_TIME_FLOOR:
+    if time_ratio < COARSE_TIME_FLOOR:
         problems.append(
             f"coarse-setup-time reduction {time_ratio:.2f}x below the "
             f"{COARSE_TIME_FLOOR}x floor (DenseLU {t_dense:.3f}s vs "
@@ -302,8 +298,6 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,
                     help="also write the JSON record to this file")
-    ap.add_argument("--small", action="store_true",
-                    help="reduced matrices (bench.py embed)")
     args = ap.parse_args(argv)
 
     import amgx_tpu
@@ -314,7 +308,7 @@ def main(argv=None):
     if jax.default_backend() == "cpu":
         # f64 end-to-end on CPU (the tier-1 configuration)
         jax.config.update("jax_enable_x64", True)
-    rec, problems = run(small=args.small)
+    rec, problems = run()
     line = json.dumps(rec)
     print(line)
     if args.out:

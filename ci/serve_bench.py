@@ -1,7 +1,7 @@
 """Serve-layer throughput: pipelined batched solves vs a sequential
 Python loop.
 
-Prints ONE JSON line (same contract as bench.py / BENCH_*.json):
+Prints ONE JSON line (the BENCH_*.json contract):
 {"metric": "serve_batched_speedup", "value": <x>, ...} — value is the
 wall-clock throughput ratio of the batched service path over a
 sequential Python loop dispatching the SAME compiled per-system solve

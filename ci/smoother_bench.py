@@ -1,6 +1,6 @@
 """Communication-free inner loops: iteration-parity + reduction gates.
 
-Prints ONE JSON line (same contract as bench.py / ci/serve_bench.py):
+Prints ONE JSON line (same contract as ci/serve_bench.py):
 {"metric": "sstep_reductions_per_s_steps", "value": <n>, ...} — value
 is the measured global reductions per s inner CG steps of the s-step
 solver (the headline communication win: ~2 vs ~3s for classic
@@ -72,14 +72,14 @@ def _amg_cfg(outer, smoother, pre, post, extra_outer=""):
     )
 
 
-def _matrix_entries(small=False):
+def _matrix_entries():
     """(name, scipy_csr, rhs) bench entries."""
     import numpy as np
     import scipy.sparse as sps
 
     from amgx_tpu.io.poisson import poisson_scipy
 
-    side = 16 if small else 24
+    side = 24
     entries = []
 
     sp = poisson_scipy((side, side)).tocsr()
@@ -119,7 +119,7 @@ def _matrix_entries(small=False):
     return entries
 
 
-def run(small=False):
+def run():
     from amgx_tpu.config.amg_config import AMGConfig
     from amgx_tpu.core.matrix import SparseMatrix
     from amgx_tpu.solvers.registry import create_solver, make_nested
@@ -132,7 +132,7 @@ def run(small=False):
             _amg_cfg(outer, smoother, pre, post, extra)
         )
         per_entry = {}
-        for ename, sp, b in _matrix_entries(small=small):
+        for ename, sp, b in _matrix_entries():
             s = make_nested(create_solver(cfg, "default"))
             s.setup(SparseMatrix.from_scipy(sp))
             res = s.solve(b)
@@ -209,8 +209,6 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,
                     help="also write the JSON record to this file")
-    ap.add_argument("--small", action="store_true",
-                    help="reduced matrix (bench.py embed)")
     args = ap.parse_args(argv)
 
     import amgx_tpu
@@ -221,7 +219,7 @@ def main(argv=None):
     if jax.default_backend() == "cpu":
         # f64 end-to-end on CPU (the tier-1 configuration)
         jax.config.update("jax_enable_x64", True)
-    rec, problems = run(small=args.small)
+    rec, problems = run()
     line = json.dumps(rec)
     print(line)
     if args.out:

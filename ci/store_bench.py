@@ -1,7 +1,7 @@
 """Setup-artifact store benchmark: cold setup vs ``load_setup``
 restore vs warm-boot service start.
 
-Prints ONE JSON line (same contract as bench.py / ci/serve_bench.py):
+Prints ONE JSON line (same contract as ci/serve_bench.py):
 ``{"metric": "store_restore_speedup", "value": <x>, ...}`` — value is
 the geometric mean over the Poisson suite of
 

@@ -59,14 +59,6 @@ JAX_PLATFORMS=cpu python ci/fleet_bench.py
 # cache hit (store regression).
 JAX_PLATFORMS=cpu python ci/store_bench.py
 
-# ---- cold-setup fast path: old-vs-new floor --------------------------
-# One JSON line; non-zero exit when the host-resident, transfer-batched
-# setup pipeline drops below 1.5x (geomean) over the reference path on
-# the Poisson suite, when the two paths' hierarchies are not
-# bitwise-identical, or when fast-path cold setup performs more than
-# one host->device transfer batch per hierarchy.
-JAX_PLATFORMS=cpu python ci/setup_bench.py
-
 # ---- cheap preconditioner: precision + inexact-coarse gates ----------
 # One JSON line; non-zero exit when the f64-refined mixed-precision or
 # INEXACT-coarse configs need more than +10% retired inner-step
@@ -165,4 +157,3 @@ echo "$dout" | grep -q "distributed solve: status=0" || {
 unset JAX_PLATFORMS
 
 python ci/acceptance.py
-python bench.py

@@ -6,8 +6,8 @@ analogue).
 
 Loads a MatrixMarket/%%NVAMGBinary file, or generates an N^3 7-pt
 Poisson system, runs y = A x on the default backend, verifies against
-the host product, and reports the marginal per-SpMV time (chain method;
-see bench.py for why plain timing lies on remote backends).
+the host product, and reports the marginal per-SpMV time (chain method:
+the difference of two dependent chains, so dispatch and transfer cancel).
 """
 
 import sys

@@ -464,6 +464,43 @@ def test_geo_galerkin_dense_reduction_matches_sparse_product():
     assert ran >= 2, ran
 
 
+def test_aggregation_hierarchy_galerkin_matches_scipy():
+    """Every coarse operator of a SIZE_4 PCG-AMG hierarchy on 12^3
+    float32 equals scipy's R A P of the downloaded level, to float32
+    rounding (a few ulps of the largest entry)."""
+    cfg = AMGConfig.from_string(
+        '{"config_version": 2, "solver": {"scope": "main",'
+        ' "solver": "PCG", "max_iters": 100, "tolerance": 1e-6,'
+        ' "convergence": "RELATIVE_INI",'
+        ' "preconditioner": {"scope": "amg", "solver": "AMG",'
+        ' "algorithm": "AGGREGATION", "selector": "SIZE_4",'
+        ' "smoother": {"scope": "jac", "solver": "BLOCK_JACOBI",'
+        ' "relaxation_factor": 0.8, "monitor_residual": 0},'
+        ' "presweeps": 1, "postsweeps": 1, "max_levels": 20,'
+        ' "min_coarse_rows": 64, "coarse_solver": "DENSE_LU_SOLVER",'
+        ' "cycle": "V", "max_iters": 1, "monitor_residual": 0}}}'
+    )
+    A = poisson_3d_7pt(12, dtype=np.float32)
+    s = create_solver(cfg, "default")
+    s.setup(A)
+    assert bool(s.solve(poisson_rhs(A.n_rows, dtype=np.float32)).converged)
+    levels = s.precond.levels
+    assert len(levels) >= 3
+    tol = 8 * np.finfo(np.float32).eps
+    for fine, coarse in zip(levels[:-1], levels[1:]):
+        Af, P, R = (
+            M.to_scipy().astype(np.float64)
+            for M in (fine.A, fine.P, fine.R)
+        )
+        ref = (R @ Af @ P).tocsr()
+        Ac = coarse.A.to_scipy()
+        assert Ac.dtype == np.float32
+        assert Ac.shape == ref.shape
+        assert abs(Ac.astype(np.float64) - ref).max() <= (
+            tol * abs(ref).max()
+        )
+
+
 def test_geo_galerkin_rejects_wrap_and_ambiguity():
     """Periodic (wrap) diagonals and thin grids with ambiguous offset
     decompositions must fall back to the sparse product, never build a

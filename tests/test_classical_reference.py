@@ -79,10 +79,23 @@ def _jump(n, contrast=10.0):
     return A
 
 
+def _dirichlet_tail(n):
+    """5-point Poisson on an n^2 grid whose last two rows are Dirichlet
+    identity rows: the strength graph then ends in empty rows, the edge
+    case of the row-segmented maxima (amg/classical.py ``_row_max``)."""
+    A = poisson_scipy((n, n)).tolil()
+    for i in (n * n - 2, n * n - 1):
+        A.rows[i] = [i]
+        A.data[i] = [1.0]
+    return A.tocsr()
+
+
 CASES = {
     "poisson10": lambda: poisson_scipy((10, 10, 10)),
     "poisson16": lambda: poisson_scipy((16, 16, 16)),
     "jump12": lambda: _jump(12),
+    "poisson48_2d": lambda: poisson_scipy((48, 48)),
+    "dirichlet24_2d": lambda: _dirichlet_tail(24),
 }
 LOCATIONS = ("HOST", "DEVICE")
 

@@ -407,7 +407,7 @@ def test_stale_dia_artifact_rejected_under_matrix_free(
 
     # simulate the old writer: same config, detection never runs
     monkeypatch.setattr(
-        AMGSolver, "_maybe_matrix_free", lambda self, A, device: A
+        AMGSolver, "_maybe_matrix_free", lambda self, A: A
     )
     monkeypatch.setattr(
         AMGSolver, "_accel_formats",
